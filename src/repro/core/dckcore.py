@@ -1583,6 +1583,11 @@ def dc_kcore(
         )
     if ckpt_retain < 1:
         raise ValueError(f"ckpt_retain must be >= 1, got {ckpt_retain}")
+    if engine == "fused":
+        # Refuse before the first divide pass, not at the first sweep.
+        from repro.kernels.fused import require_fused_platform
+
+        require_fused_platform()
     if decompose_fn is None:
         decompose_fn = (  # noqa: E731
             lambda bg, **kw: decompose(bg, op=engine, int16=int16, **kw)

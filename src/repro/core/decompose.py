@@ -16,9 +16,11 @@ mask in the inner loop. Per iteration, per degree-bucket:
 Four interchangeable sweep engines (``op=``):
   * ``"sorted"`` — descending sort + prefix scan (paper's literal loop).
   * ``"count"``  — sort-free suffix counts (pure jnp).
-  * ``"kernel"`` — the Pallas TPU h-index kernel (interpret mode on CPU),
-    with the degeneracy-bounded candidate window.
-  * ``"fused"``  — the fused Pallas sweep kernel (``kernels.fused``):
+  * ``"kernel"`` — the Pallas TPU h-index kernel, with the
+    degeneracy-bounded candidate window; compiled by Mosaic on a TPU,
+    interpreted on the CPU backend.
+  * ``"fused"``  — the fused Pallas sweep kernel (``kernels.fused``), CPU
+    backend only (Mosaic refuses it; ``decompose`` raises elsewhere):
     gather + h-index + dirty-bit push in ONE kernel per row tile, the
     gathered matrix never materialized. With few tiles each bucket keeps
     its own ``lax.cond``-gated launch (bit-identical trajectory to the
@@ -493,6 +495,10 @@ def decompose(
     """
     n = bg.n_nodes
     t0 = time.perf_counter()
+    if op == "fused":
+        from repro.kernels.fused import require_fused_platform
+
+        require_fused_platform()
     est_dtype = jnp.int32
     if int16:
         if op != "fused":

@@ -49,7 +49,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map as compat_shard_map
 from repro.core.decompose import DecomposeResult
 from repro.core.hindex import hindex_of_sequence
 from repro.graph.structs import BucketedGraph
@@ -408,7 +407,7 @@ def make_sweep_fn(plan: MeshPlan, cand: int, wire_dtype=jnp.int32,
         slot axes + all_gather over node axes before every scatter), but the
         static checker cannot see through the scatter."""
         return jax.jit(
-            compat_shard_map(
+            jax.shard_map(
                 sweep,
                 mesh=mesh,
                 in_specs=(rep, rep, rep, rep, [(row_p, tile_p)] * n_buckets),
@@ -613,7 +612,7 @@ def device_external_info(
                 part = jax.lax.psum(part, all_axes)
             return part
 
-        return compat_shard_map(
+        return jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(P(all_axes), P(all_axes), P(), P()),

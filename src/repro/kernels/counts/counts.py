@@ -3,9 +3,10 @@
 The distributed engine splits each node's neighbor slots over the "model"
 axis; every shard computes suffix counts over its local slots and the
 engine psums them (core/distributed.py). This kernel is that local compute
-with explicit VMEM tiling: grid over (node tiles x candidate tiles), inner
-accumulation over neighbor-slot chunks so the compare footprint
-``tile_n x slot_chunk x tile_c`` stays in VMEM regardless of bucket width.
+with explicit VMEM tiling: grid over (node tiles x candidate tiles x
+neighbor-slot chunks), the counts accumulating in the output block across
+the slot chunks, so the compare footprint ``tile_n x slot_chunk x tile_c``
+and the kernel body stay the same whatever the bucket width.
 
 Compared to the fused hindex kernel (kernels/hindex), the output here is
 the [n, cand] count matrix — the collective payload — rather than the
@@ -13,30 +14,30 @@ final estimate, because feasibility can only be decided after the psum.
 """
 from __future__ import annotations
 
-import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
 
-def _counts_kernel(neigh_ref, ext_ref, out_ref, *, slot_chunk: int):
-    x = neigh_ref[...]  # [tile_n, w_local]
+
+def _counts_kernel(neigh_ref, ext_ref, out_ref):
+    x = neigh_ref[...]  # [tile_n, slot_chunk]
     ext = ext_ref[...]  # [tile_n, 1]
-    tile_n, w = x.shape
     tile_c = out_ref.shape[1]
     c0 = pl.program_id(1) * tile_c
     i = c0 + 1 + jax.lax.broadcasted_iota(jnp.int32, (1, tile_c), 1)
     thr = ext + i  # [tile_n, tile_c]
 
-    acc = jnp.zeros((tile_n, tile_c), jnp.int32)
-    for lo in range(0, w, slot_chunk):
-        hi = min(lo + slot_chunk, w)
-        xs = x[:, lo:hi]
-        acc = acc + jnp.sum(
-            (xs[:, :, None] >= thr[:, None, :]).astype(jnp.int32), axis=1
-        )
-    out_ref[...] = acc
+    @pl.when(pl.program_id(2) == 0)
+    def _init():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    out_ref[...] += jnp.sum(
+        (x[:, :, None] >= thr[:, None, :]).astype(jnp.int32), axis=1
+    )
 
 
 def partial_counts_pallas(
@@ -47,24 +48,32 @@ def partial_counts_pallas(
     tile_n: int = 8,
     tile_c: int = 128,
     slot_chunk: int = 512,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
-    """neigh: [n, w_local] int32 (-1 pad); ext: [n] -> [n, cand] int32."""
+    """neigh: [n, w_local] int32 (-1 pad); ext: [n] -> [n, cand] int32.
+
+    Rows wider than ``slot_chunk`` are padded with -1 slots to a multiple
+    of it. ``interpret=None`` interprets only on the CPU backend."""
     n, w = neigh.shape
     if n % tile_n != 0:
         raise ValueError(f"rows {n} not a multiple of tile_n {tile_n}")
     cand_pad = -(-cand // tile_c) * tile_c
+    neigh = neigh.astype(jnp.int32)
+    if w > slot_chunk:
+        neigh = jnp.pad(neigh, ((0, 0), (0, (-w) % slot_chunk)),
+                        constant_values=-1)
+    else:
+        slot_chunk = w
     ext2 = ext.reshape(n, 1).astype(jnp.int32)
-    kernel = functools.partial(_counts_kernel, slot_chunk=slot_chunk)
     out = pl.pallas_call(
-        kernel,
-        grid=(n // tile_n, cand_pad // tile_c),
+        _counts_kernel,
+        grid=(n // tile_n, cand_pad // tile_c, neigh.shape[1] // slot_chunk),
         in_specs=[
-            pl.BlockSpec((tile_n, w), lambda gn, gc: (gn, 0)),
-            pl.BlockSpec((tile_n, 1), lambda gn, gc: (gn, 0)),
+            pl.BlockSpec((tile_n, slot_chunk), lambda gn, gc, gk: (gn, gk)),
+            pl.BlockSpec((tile_n, 1), lambda gn, gc, gk: (gn, 0)),
         ],
-        out_specs=pl.BlockSpec((tile_n, tile_c), lambda gn, gc: (gn, gc)),
+        out_specs=pl.BlockSpec((tile_n, tile_c), lambda gn, gc, gk: (gn, gc)),
         out_shape=jax.ShapeDtypeStruct((n, cand_pad), jnp.int32),
-        interpret=interpret,
-    )(neigh.astype(jnp.int32), ext2)
+        interpret=resolve_interpret(interpret),
+    )(neigh, ext2)
     return out[:, :cand]

@@ -2,6 +2,7 @@
 from __future__ import annotations
 
 from functools import partial
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -11,7 +12,7 @@ from repro.kernels.counts.counts import partial_counts_pallas
 
 @partial(jax.jit, static_argnames=("cand", "interpret"))
 def partial_counts_op(neigh: jax.Array, ext: jax.Array, *, cand: int,
-                      interpret: bool = True) -> jax.Array:
+                      interpret: Optional[bool] = None) -> jax.Array:
     n, w = neigh.shape
     tile_n = 8
     pad = (-n) % tile_n

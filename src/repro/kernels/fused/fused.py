@@ -20,19 +20,26 @@ neighbor tile while it is resident in VMEM:
     an output block revisited by every grid step: zero-initialised on step
     0 (``pl.when``) and max-accumulated afterwards.
 
-On TPU the estimate vector would live in ANY/HBM with DMA'd gathers; in
-interpret mode (this container) block loads are plain XLA slices, so the
-kernel doubles as the executable spec. The estimate vector may be int16
+The kernel runs only in interpret mode, on the CPU backend, where block
+loads are plain XLA slices and the kernel doubles as the executable spec.
+Mosaic refuses it for the TPU (``NotImplementedError: Only 2D gather is
+supported``, from the in-kernel gathers ``c[neigh]`` and ``ext_pad[ids]``),
+so :func:`repro.kernels.fused.ops.require_fused_platform` stops it there.
+A TPU version keeps the estimate vector in ANY/HBM with DMA'd gathers
+instead of whole-``[n+1]`` VMEM blocks. The estimate vector may be int16
 (the opt-in halved-wire mode — see ``core.decompose``); all arithmetic is
 widened to int32 in-kernel, only the resident state is narrow.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import resolve_interpret
 
 
 def _fused_sweep_kernel(
@@ -105,7 +112,7 @@ def fused_sweep_pallas(
     tile_n: int = 8,
     cand_chunk: int = 128,
     track_dirty: bool = True,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """Fused sweep over one bucket tile set.
 
@@ -150,7 +157,7 @@ def fused_sweep_pallas(
             jax.ShapeDtypeStruct((rows, 1), jnp.int32),
             jax.ShapeDtypeStruct((n1,), jnp.int8),
         ),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(c, ext_pad.astype(jnp.int32), ids2, neigh.astype(jnp.int32))
     return est, changed, dirty
 

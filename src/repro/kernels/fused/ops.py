@@ -9,6 +9,7 @@ width group.
 from __future__ import annotations
 
 from functools import partial
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -17,6 +18,26 @@ from repro.kernels.fused.fused import fused_sweep_pallas, fused_vmem_bytes_estim
 
 # Same conservative working budget as kernels.hindex.ops.
 _VMEM_BUDGET = 8 * 1024 * 1024
+
+# What Mosaic answers when asked to compile the kernel for a TPU.
+MOSAIC_REFUSAL = "NotImplementedError: Only 2D gather is supported"
+
+
+def require_fused_platform() -> None:
+    """Refuse the fused kernel on any backend but the CPU.
+
+    Mosaic does not lower the kernel's in-kernel gathers (``c[neigh]``,
+    ``ext_pad[ids]``), and running the interpreter on an accelerator would
+    hide that. Callers check before the first sweep.
+    """
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise NotImplementedError(
+            f"engine='fused' (and int16, which needs it) cannot run on the "
+            f"{backend} backend: Mosaic refuses the fused sweep kernel "
+            f"({MOSAIC_REFUSAL}, from its in-kernel gathers). Use "
+            f"engine='kernel' or 'sorted'."
+        )
 
 
 def pick_fused_tile_n(width: int, cand_chunk: int = 128,
@@ -38,7 +59,7 @@ def fused_sweep_op(
     *,
     cand: int,
     track_dirty: bool = True,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """Fused gather + h-index + dirty push for one bucket.
 

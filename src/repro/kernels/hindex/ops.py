@@ -7,6 +7,7 @@ exposes a drop-in replacement for :func:`repro.core.hindex.hindex_count`
 from __future__ import annotations
 
 from functools import partial
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -16,11 +17,18 @@ from repro.kernels.hindex.hindex import hindex_pallas, vmem_bytes_estimate
 # Conservative per-core VMEM working budget (v5e has 128 MiB VMEM; leave
 # headroom for Mosaic's own buffers and double buffering).
 _VMEM_BUDGET = 8 * 1024 * 1024
+# Neighbor slots per grid step; wider rows take several steps.
+_SLOT_CHUNK = 512
 
 
-def pick_tile_n(width: int, cand_chunk: int = 128, budget: int = _VMEM_BUDGET) -> int:
+def pick_tile_n(width: int, cand: int = 0, cand_chunk: int = 128,
+                budget: int = _VMEM_BUDGET) -> int:
+    """Largest power-of-two row tile whose footprint fits ``budget``; the
+    kernel holds at most ``_SLOT_CHUNK`` slots of a row at once."""
+    chunk = min(width, _SLOT_CHUNK)
     tile_n = 256
-    while tile_n > 8 and vmem_bytes_estimate(tile_n, width, cand_chunk) > budget:
+    while tile_n > 8 and vmem_bytes_estimate(
+            tile_n, chunk, cand_chunk, cand) > budget:
         tile_n //= 2
     return tile_n
 
@@ -32,7 +40,7 @@ def hindex_op(
     cur: jax.Array,
     *,
     cand: int,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """H-index for one padded bucket. Pads rows to the tile multiple.
 
@@ -41,15 +49,17 @@ def hindex_op(
       ext: [n] int32 external information.
       cur: [n] int32 current estimates (kernel predication hint).
       cand: candidate window (degeneracy bound U; >= k_max for exactness).
+      interpret: ``None`` = interpret only on the CPU backend.
     """
     n, w = neigh_cores.shape
-    tile_n = pick_tile_n(w)
+    tile_n = pick_tile_n(w, min(cand, w))
     n_pad = (-n) % tile_n
     if n_pad:
         neigh_cores = jnp.pad(neigh_cores, ((0, n_pad), (0, 0)), constant_values=-1)
         ext = jnp.pad(ext, (0, n_pad))
         cur = jnp.pad(cur, (0, n_pad))
     out = hindex_pallas(
-        neigh_cores, ext, cur, cand=cand, tile_n=tile_n, interpret=interpret
+        neigh_cores, ext, cur, cand=cand, tile_n=tile_n,
+        slot_chunk=_SLOT_CHUNK, interpret=interpret,
     )
     return out[:n]

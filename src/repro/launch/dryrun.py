@@ -27,7 +27,6 @@ import traceback
 
 import jax
 
-from repro.compat import cost_analysis_dict
 from repro.configs import SHAPES, cells
 from repro.launch.mesh import make_production_mesh
 from repro.launch.specs import input_specs, rules_for
@@ -87,7 +86,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, rules=None,
         t_compile = time.perf_counter() - t0 - t_lower
 
     mem = compiled.memory_analysis()
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     print(mem)
     print({k: v for k, v in cost.items() if k in ("flops", "bytes accessed")})
     hlo = compiled.as_text()

@@ -33,7 +33,8 @@ a locality-aware node ordering to each part before tiling
 (``auto`` = degree-profile autotuner, ``none`` = one tile per degree
 class). ``--engine {sorted,count,kernel,fused}`` selects the conquer
 sweep engine — ``fused`` is the single-kernel Pallas sweep (gather +
-h-index + dirty push fused per row tile; interpret mode on CPU) — and
+h-index + dirty push fused per row tile; CPU backend only, since Mosaic
+refuses it for the TPU) — and
 ``--int16`` opts the fused engine into the halved-width estimate mode
 (falls back to int32 automatically when any starting estimate reaches
 2^15; coreness is bit-identical in every case). With ``--part-parallel``,
@@ -57,6 +58,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import time
 
 from repro.core.dckcore import dc_kcore
@@ -246,11 +248,13 @@ def main():
     ap.add_argument("--fault-log", default=None, metavar="FILE",
                     help="write the fault/recovery event trail as JSON")
     ap.add_argument("--devices", type=int, default=None, metavar="N",
-                    help="force N virtual host devices and run the "
-                         "shard_map engine over a data x model mesh split "
-                         "into --part-parallel slices, with device-resident "
-                         "E(v) boundary exchange (requires --part-parallel; "
-                         "N must be divisible by S)")
+                    help="run the shard_map engine over a data x model mesh "
+                         "of the first N devices, split into --part-parallel "
+                         "slices, with device-resident E(v) boundary "
+                         "exchange (requires --part-parallel; N must be "
+                         "divisible by S). Under JAX_PLATFORMS=cpu the N "
+                         "devices are virtual host devices; elsewhere they "
+                         "are the real chips, and fewer than N is an error")
     ap.add_argument("--check", action="store_true", help="verify vs BZ peeling")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
@@ -287,20 +291,25 @@ def main():
 
     part_parallel_plan = None
     if args.devices is not None:
-        # Flag edit must precede the first backend query; every import so
-        # far touches only numpy/argparse, so the backend is still cold.
         from repro.launch.mesh import (
             force_host_device_count,
             make_mesh_plan_for_devices,
         )
 
-        force_host_device_count(args.devices)
+        if os.environ.get("JAX_PLATFORMS") == "cpu":
+            # CPU runs emulate the mesh. The flag edit must precede the
+            # first backend query; every import so far touches only
+            # numpy/argparse, so the backend is still cold.
+            force_host_device_count(args.devices)
         # Slot-shard over "model" when the "data" axis still divides into
         # --part-parallel slices afterwards; otherwise keep the mesh flat.
         mp = 2 if args.devices % (2 * args.part_parallel) == 0 else 1
-        part_parallel_plan = make_mesh_plan_for_devices(
-            args.devices, model_parallel=mp
-        )
+        try:
+            part_parallel_plan = make_mesh_plan_for_devices(
+                args.devices, model_parallel=mp
+            )
+        except ValueError as e:
+            ap.error(str(e))
 
     t0 = time.perf_counter()
     g, ingest = load_graph(args.graph, args.seed, edge_chunk=args.edge_chunk)
@@ -430,4 +439,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -114,7 +114,6 @@ def run_case(name, n, m, cand, wire, multi_pod=True, tag="", n_iters=30):
     import jax
     import jax.numpy as jnp
 
-    from repro.compat import cost_analysis_dict
     from repro.core.distributed import (
         MeshPlan,
         make_sweep_fn,
@@ -195,7 +194,7 @@ def run_case(name, n, m, cand, wire, multi_pod=True, tag="", n_iters=30):
         compiled = lowered.compile()
     rec["compile_s"] = round(time.perf_counter() - t0, 1)
     mem = compiled.memory_analysis()
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     colls = parse_collectives(compiled.as_text())
     rl = roofline_terms(
         float(cost.get("flops", 0.0)),

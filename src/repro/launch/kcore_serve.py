@@ -105,7 +105,9 @@ def _update_loop(
         state["error"] = exc
 
 
-def main(argv=None):
+def main(argv=None, publisher: SnapshotPublisher | None = None):
+    """Run the server; returns its metrics. ``publisher`` (a fresh one by
+    default) lets an in-process caller read the final snapshot."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--graph", default="rmat:12:8")
     ap.add_argument("--edit-log", required=True,
@@ -143,7 +145,7 @@ def main(argv=None):
     g, _ = load_graph(args.graph, args.seed)
     t0 = time.perf_counter()
     boot = decompose(bucketize(g), op=args.engine)
-    pub = SnapshotPublisher()
+    pub = SnapshotPublisher() if publisher is None else publisher
     pub.publish(g, boot.coreness)
     print(f"boot: n={g.n_nodes:,} m={g.n_edges:,} "
           f"k_max={int(boot.coreness.max(initial=0))} "
@@ -230,4 +232,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -1,11 +1,9 @@
 """Production meshes.
 
 Defined as FUNCTIONS so importing this module never touches jax device
-state (the dry-run must set XLA_FLAGS before any device query).
-
-All mesh construction routes through :func:`repro.compat.make_mesh`, the
-version-portable helper (``axis_types=Auto`` where supported, omitted on
-JAX 0.4.x which has no ``jax.sharding.AxisType``).
+state (the dry-run must set XLA_FLAGS before any device query). Every mesh
+is built with ``AxisType.Auto`` axes, the behaviour the sharding policy
+assumes (``jax.make_mesh`` defaults to ``Explicit``).
 
 Single pod: 16x16 = 256 v5e chips, axes ("data", "model").
 Multi-pod:  2 x 16 x 16 = 512 chips, axes ("pod", "data", "model") — the
@@ -14,19 +12,34 @@ cross-pod (DCN-class) traffic minimal.
 """
 from __future__ import annotations
 
-from repro.compat import make_mesh
+import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_mesh_for_devices(n: int, model_parallel: int = 1, axis_names=("data", "model")):
-    """Small helper for tests / examples on N local (virtual) devices."""
-    assert n % model_parallel == 0
-    return make_mesh((n // model_parallel, model_parallel), axis_names)
+    """An ``(n / model_parallel) x model_parallel`` mesh over the first
+    ``n`` of ``jax.devices()``: the real chips on an accelerator, virtual
+    host devices under :func:`force_host_device_count`. Raises when the
+    backend has fewer than ``n`` devices."""
+    if n % model_parallel != 0:
+        raise ValueError(f"{n} devices do not split into model_parallel="
+                         f"{model_parallel}")
+    devices = jax.devices()
+    if len(devices) < n:
+        raise ValueError(
+            f"asked for a {n}-device mesh, but the {devices[0].platform} "
+            f"backend has {len(devices)} device(s)"
+        )
+    return jax.make_mesh(
+        (n // model_parallel, model_parallel), axis_names,
+        axis_types=(AxisType.Auto,) * len(axis_names), devices=devices[:n],
+    )
 
 
 def make_mesh_plan_for_devices(n: int, model_parallel: int = 1):
@@ -42,21 +55,35 @@ def make_mesh_plan_for_devices(n: int, model_parallel: int = 1):
     )
 
 
-def force_host_device_count(n: int) -> None:
-    """Make the CPU host expose ``n`` virtual devices (test/emulation
-    backend for part-parallel runs) by rewriting ``XLA_FLAGS``.
+def _backends_initialized() -> bool:
+    """Has jax already instantiated a backend (device queries ran)?
 
-    Must run BEFORE jax instantiates a backend — the flag is read once at
-    backend init, so a late call would silently do nothing; this raises
-    instead (via :func:`repro.compat.backends_initialized`). Any previous
+    Reaches into ``jax._src.xla_bridge`` (no public probe exists); defaults
+    to ``False`` if the internal layout shifts — the worst case is a clear
+    late-flag failure instead of an early one.
+    """
+    try:
+        from jax._src import xla_bridge
+
+        return bool(xla_bridge._backends)
+    except (ImportError, AttributeError):
+        return False
+
+
+def force_host_device_count(n: int) -> None:
+    """Make the CPU host expose ``n`` virtual devices (the test/emulation
+    backend for multi-device runs) by rewriting ``XLA_FLAGS``.
+
+    Only CPU runs use it: on an accelerator the mesh is built from the
+    real devices. Must run BEFORE jax instantiates a backend — the flag is
+    read once at backend init, so a late call would silently do nothing;
+    this raises instead. Any previous
     ``--xla_force_host_platform_device_count`` token is dropped so repeated
     calls don't accumulate contradictory flags.
     """
     import os
 
-    from repro.compat import backends_initialized
-
-    if backends_initialized():
+    if _backends_initialized():
         raise RuntimeError(
             "force_host_device_count must be called before jax initializes "
             "its backends (the flag is read once at backend init)"
@@ -76,12 +103,14 @@ def init_multiprocess(
     local_device_ids=None,
 ) -> None:
     """Join this process to a multi-process jax mesh (one host per mesh
-    slice in the part-parallel deployment story). Thin wrapper over
-    :func:`repro.compat.distributed_initialize` so the version-sensitive
-    call stays in the compat layer; after it returns, ``jax.devices()``
-    spans every process and the global MeshPlan can be built as usual."""
-    from repro.compat import distributed_initialize
-
-    distributed_initialize(
-        coordinator_address, num_processes, process_id, local_device_ids
+    slice in the part-parallel deployment story); after it returns,
+    ``jax.devices()`` spans every process and the global MeshPlan can be
+    built as usual. Not for one host's chips: one process drives them all."""
+    jax.distributed.initialize(
+        coordinator_address=coordinator_address,
+        num_processes=int(num_processes),
+        process_id=int(process_id),
+        local_device_ids=(
+            None if local_device_ids is None else list(local_device_ids)
+        ),
     )

@@ -21,7 +21,6 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.compat import optimization_barrier
 from repro.models import attention as attn_lib
 from repro.models import ssm as ssm_lib
 from repro.models.layers import layernorm, layernorm_specs, rmsnorm, rmsnorm_specs
@@ -244,10 +243,10 @@ def stack_apply(params, x, cfg, ctx, n_layers: Optional[int] = None,
         def group_fn(x, group_params):
             # Barrier: without it XLA hoists the first-use f32 upcast of x
             # out of the backward scan, materializing the whole residual
-            # stash in f32 (2x the bf16 stash; measured on grok-1). The
-            # compat wrapper keeps it differentiable on JAX 0.4.x and
-            # barriers the cotangent on the backward path too.
-            x = optimization_barrier(x)
+            # stash in f32 (2x the bf16 stash; measured on grok-1). Its
+            # differentiation rule barriers the cotangent on the backward
+            # path too.
+            x = jax.lax.optimization_barrier(x)
             aux = jnp.float32(0)
             gcache = {}
             for i, kind in enumerate(kinds):

@@ -7,14 +7,14 @@ from distributed_helpers import run_with_devices
 
 _CODE = r"""
 import jax, json
-from repro.compat import cost_analysis_dict, make_mesh
+from jax.sharding import AxisType
 from repro.launch.specs import input_specs, rules_for
 from repro.launch.steps import step_fn_for
 from repro.sharding.policy import active_mesh
 from repro.configs import SHAPES
 from repro.roofline.analysis import parse_collectives
 
-mesh = make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 arch, shape_name = "%ARCH%", "%SHAPE%"
 specs, cfg, log = input_specs(arch, shape_name, mesh)
 kind = SHAPES[shape_name].kind
@@ -24,7 +24,7 @@ with mesh, active_mesh(mesh):
     lowered = jax.jit(fn).lower(**kwargs)
     compiled = lowered.compile()
 mem = compiled.memory_analysis()
-cost = cost_analysis_dict(compiled)
+cost = compiled.cost_analysis()
 colls = parse_collectives(compiled.as_text())
 assert cost["flops"] > 0
 assert mem.temp_size_in_bytes >= 0

@@ -547,8 +547,8 @@ _ELASTIC_SAVE = r"""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P, NamedSharding
 from repro.ckpt import save_pytree
-from repro.compat import make_mesh
-mesh = make_mesh((4, 2), ("data", "model"))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((4, 2), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 w = jax.device_put(jnp.arange(64*32, dtype=jnp.float32).reshape(64, 32),
                    NamedSharding(mesh, P("data", "model")))
 b = jax.device_put(jnp.ones((32,), jnp.float32), NamedSharding(mesh, P("model")))
@@ -560,9 +560,9 @@ _ELASTIC_RESTORE = r"""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P, NamedSharding
 from repro.ckpt import restore_pytree_with_fallback
-from repro.compat import make_mesh
+from jax.sharding import AxisType
 assert len(jax.devices()) == 4
-mesh = make_mesh((2, 2), ("data", "model"))
+mesh = jax.make_mesh((2, 2), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 template = {"w": np.zeros((64, 32), np.float32), "b": np.zeros((32,), np.float32)}
 shardings = {"w": NamedSharding(mesh, P("data", "model")),
              "b": NamedSharding(mesh, P("model"))}

@@ -14,7 +14,6 @@ import numpy as np
 import pytest
 
 from distributed_helpers import run_with_devices
-from repro.compat import cost_analysis_dict
 from repro.configs.base import ModelConfig, SHAPES, ShapeConfig
 from repro.models.model import build_specs, forward
 from repro.models.module import count_params, init_params
@@ -42,9 +41,9 @@ def test_collectives_loop_corrected():
         r"""
 import jax, jax.numpy as jnp, json
 from jax.sharding import PartitionSpec as P, NamedSharding
-from repro.compat import make_mesh
+from jax.sharding import AxisType
 from repro.roofline.analysis import parse_collectives
-mesh = make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 def body(x, w):
     return jnp.tanh(x @ w), None
 def f(x, ws):
@@ -87,7 +86,7 @@ def test_analytic_flops_matches_hlo_unrolled():
         return logits
 
     compiled = jax.jit(fwd).lower(params_abs, tokens).compile()
-    hlo_flops = float(cost_analysis_dict(compiled)["flops"])
+    hlo_flops = float(compiled.cost_analysis()["flops"])
     analytic = flops_model.cost(cfg, shape, count_params(specs), n_chips=1).flops_total
     # n_layers=1 => the stack scan has trip count 1, so HLO is loop-exact
     # here; softmax/norm flops make HLO slightly larger.

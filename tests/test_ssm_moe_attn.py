@@ -178,9 +178,9 @@ def test_moe_grouped_dispatch_matches_dense():
     got1, _ = moe(params, x, cfg)  # g=1 (no active mesh)
     # Force g=4 grouping under a real (trivial, 1-device) mesh so the
     # logical constraints resolve.
-    from repro.compat import make_mesh
+    from jax.sharding import AxisType
 
-    mesh = make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     saved = (sp._ACTIVE_AXES, sp._ACTIVE_RULES)
     try:
         sp._ACTIVE_AXES = {"data": 4}
