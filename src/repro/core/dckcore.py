@@ -89,6 +89,7 @@ from __future__ import annotations
 
 import concurrent.futures
 import dataclasses
+import functools
 import logging
 import os
 import re
@@ -101,6 +102,16 @@ import numpy as np
 
 from repro.core.decompose import DecomposeResult, decompose
 from repro.core.divide import timed_candidates
+from repro.core.spans import (
+    SpanRecord,
+    StageTime,
+    bound,
+    count,
+    current,
+    recording,
+    span,
+    stage_seconds,
+)
 from repro.graph.build import (
     DivideStats,
     _resolve_chunk_slots,
@@ -239,6 +250,14 @@ class DCKCoreReport:
     degraded_waves: int = 0
     quarantined_steps: int = 0
     fault_events: List[dict] = dataclasses.field(default_factory=list)
+    # The run's program spans (repro.core.spans), from every thread of it.
+    spans: List[SpanRecord] = dataclasses.field(default_factory=list)
+
+    def stage_seconds(self) -> Dict[str, StageTime]:
+        """Total seconds, self seconds and count of the run's spans, by
+        span name (``kcore.part``, ``kcore.divide.extract``, ``kcore.sweep``
+        ...)."""
+        return stage_seconds(self.spans)
 
     @property
     def total_comm(self) -> int:
@@ -286,9 +305,11 @@ class DCKCoreReport:
 
     @property
     def idle_fraction(self) -> float:
-        """Fraction of the run's wall clock the accelerator spent NOT
-        sweeping (divide passes, bucketize, checkpoint saves, merge) — the
-        stall metric ``overlap=True`` exists to shrink."""
+        """Host share of the run's wall clock spent outside the conquer
+        engine's calls (divide passes, bucketize, checkpoint saves, merge)
+        — the stall ``overlap=True`` exists to shrink. It is host time over
+        host time: the device's idle share comes only from a profiler
+        trace."""
         if self.total_time_s <= 0:
             return 0.0
         return max(0.0, 1.0 - self.total_decompose_time_s / self.total_time_s)
@@ -672,6 +693,36 @@ class _Prefetch:
     plan: Optional[PartPlan] = None
 
 
+# A ``kcore.part`` span opens with these counts, so that the executables
+# JAX builds while it is open are counted on it (repro.core.spans).
+_PART_COUNTS = {"compiles": 0, "compile_ms": 0.0}
+
+
+def _count_part(plan: PartPlan) -> None:
+    """Size and threshold of ``plan``'s part, on the open ``kcore.part``
+    span (no threshold on the rest part)."""
+    g = plan.part_g
+    count("n_nodes", 0 if g is None else g.n_nodes)
+    count("n_edges", 0 if g is None else g.n_edges)
+    if plan.threshold is not None:
+        count("threshold", plan.threshold)
+
+
+def _recorded(run):
+    """Run ``run`` (:func:`dc_kcore`) under a fresh span recorder, inside
+    a ``kcore.job`` span, and put the records on its report."""
+
+    @functools.wraps(run)
+    def wrapper(*args, **kwargs):
+        with recording() as recorder:
+            with span("kcore.job"):
+                core, report = run(*args, **kwargs)
+        report.spans = recorder.records
+        return core, report
+
+    return wrapper
+
+
 class _PartPipeline:
     """The staged scheduler behind :func:`dc_kcore`.
 
@@ -764,6 +815,8 @@ class _PartPipeline:
 
         self.parts: List[PartReport] = state.reports
         self.preprocess_time_s = 0.0
+        # The run's span recorder, handed to the worker threads.
+        self.recorder = current()
         self.prefetch_hits = 0
         self.prefetch_misses = 0
         self._future: Optional[concurrent.futures.Future] = None
@@ -806,12 +859,13 @@ class _PartPipeline:
                     cand_mask=cand_mask, dstats=dstats,
                     extract_time_s=extract_time, speculative=speculative,
                 )
-            t0 = time.perf_counter()
-            part_g, part_local_ids = induced_subgraph(
-                graph, cand_mask, chunk_slots=self.divide_chunk, stats=dstats
-            )
-            part_ext = ext[cand_mask]
-            extract_time += time.perf_counter() - t0
+            with span("kcore.divide.extract") as sp:
+                part_g, part_local_ids = induced_subgraph(
+                    graph, cand_mask, chunk_slots=self.divide_chunk,
+                    stats=dstats,
+                )
+                part_ext = ext[cand_mask]
+            extract_time += sp.seconds
             return PartPlan(
                 cursor=cursor, name=f"core>={t}", threshold=t,
                 part_g=part_g, part_local_ids=part_local_ids,
@@ -839,20 +893,20 @@ class _PartPipeline:
         divide stage (prefetched plans arrive with ``bg`` already built)."""
         if plan.bg is not None or plan.part_g is None:
             return
-        t0 = time.perf_counter()
         # Reorder the part, not the whole graph: each part is a fresh id
         # space, and locality only has to hold within the tiles actually
         # decomposed together. part_ext stays in part-local original order;
         # bucketize permutes it in and the engine un-permutes coreness out.
-        plan.bg = bucketize(
-            reorder_graph(
-                plan.part_g, self.reorder,
-                sample_edges=self.reorder_sample_edges,
-            ),
-            ext=plan.part_ext, row_align=self.row_align,
-            max_bucket_rows=self.max_bucket_rows,
-        )
-        plan.bucketize_time_s = time.perf_counter() - t0
+        with span("kcore.divide.bucketize") as sp:
+            plan.bg = bucketize(
+                reorder_graph(
+                    plan.part_g, self.reorder,
+                    sample_edges=self.reorder_sample_edges,
+                ),
+                ext=plan.part_ext, row_align=self.row_align,
+                max_bucket_rows=self.max_bucket_rows,
+            )
+        plan.bucketize_time_s = sp.seconds
 
     # ---------------- prefetch stage ---------------- #
     def _submit_prefetch(self, plan: PartPlan) -> None:
@@ -900,30 +954,32 @@ class _PartPipeline:
         finalizes — the shared speculation body of the overlap prefetch
         (depth 1, worker thread) and the part-parallel wave planner
         (depth ``part_parallel``, main thread)."""
-        t0 = time.perf_counter()
-        stats = self._fresh_stats()
-        keep_local = ~cand_mask
-        ext_delta = self._fold_external(graph, keep_local, cand_mask, stats)
-        shrink_graph, keep_ids = induced_subgraph(
-            graph, keep_local, chunk_slots=self.divide_chunk, stats=stats
-        )
-        ext_next = ext[keep_local] + ext_delta
+        with span("kcore.divide.fold") as sp:
+            stats = self._fresh_stats()
+            keep_local = ~cand_mask
+            ext_delta = self._fold_external(graph, keep_local, cand_mask,
+                                            stats)
+            shrink_graph, keep_ids = induced_subgraph(
+                graph, keep_local, chunk_slots=self.divide_chunk, stats=stats
+            )
+            ext_next = ext[keep_local] + ext_delta
         return _Prefetch(
             base_cursor=cursor, shrink_graph=shrink_graph,
             shrink_keep_ids=keep_ids, ext_next=ext_next,
-            shrink_stats=stats, shrink_time_s=time.perf_counter() - t0,
+            shrink_stats=stats, shrink_time_s=sp.seconds,
         )
 
     def _prefetch_task(self, graph: Graph, ext: np.ndarray,
                        cand_mask: np.ndarray, cursor: int) -> _Prefetch:
-        self._visit_fault("prefetch", cursor=cursor)
-        pf = self._speculative_shrink(graph, ext, cand_mask, cursor)
-        pf.plan = self._plan_on(
-            pf.shrink_graph, pf.ext_next, cursor + 1, speculative=True
-        )
-        if pf.plan is not None:
-            self._bucketize(pf.plan)
-        return pf
+        with bound(self.recorder):
+            self._visit_fault("prefetch", cursor=cursor)
+            pf = self._speculative_shrink(graph, ext, cand_mask, cursor)
+            pf.plan = self._plan_on(
+                pf.shrink_graph, pf.ext_next, cursor + 1, speculative=True
+            )
+            if pf.plan is not None:
+                self._bucketize(pf.plan)
+            return pf
 
     def _take_prefetch(self, cursor: int) -> Optional[_Prefetch]:
         """Join the in-flight prefetch (if any). Worker failures re-raise
@@ -1040,16 +1096,18 @@ class _PartPipeline:
         append its report (before the shrink — matching the report order
         the checkpoints have always serialized)."""
         state = self.state
-        # Finalize nodes that resolved at >= t (all of them for Exact-Divide).
-        final_local = res.coreness >= plan.threshold
-        part_orig_ids = state.remaining_ids[plan.part_local_ids]
-        newly = part_orig_ids[final_local]
-        state.coreness[newly] = res.coreness[final_local]
-        state.finalized[newly] = True
-        report = self._report_for(
-            plan, res, density, start_sweep, int(final_local.sum())
-        )
-        self.parts.append(report)
+        with span("kcore.merge"):
+            # Finalize nodes that resolved at >= t (all of them for
+            # Exact-Divide).
+            final_local = res.coreness >= plan.threshold
+            part_orig_ids = state.remaining_ids[plan.part_local_ids]
+            newly = part_orig_ids[final_local]
+            state.coreness[newly] = res.coreness[final_local]
+            state.finalized[newly] = True
+            report = self._report_for(
+                plan, res, density, start_sweep, int(final_local.sum())
+            )
+            self.parts.append(report)
         return report, final_local
 
     def _shrink(self, plan: PartPlan, final_local: np.ndarray,
@@ -1086,37 +1144,40 @@ class _PartPipeline:
         """The sequential fold: shrink the remaining graph by the part's
         ACTUALLY finalized nodes."""
         state = self.state
-        t0 = time.perf_counter()
-        newly_mask_local = np.zeros(self.remaining_graph.n_nodes, dtype=bool)
-        newly_mask_local[plan.part_local_ids[final_local]] = True
-        keep_local = ~newly_mask_local
-        ext_delta = self._fold_external(
-            self.remaining_graph, keep_local, newly_mask_local, plan.dstats
-        )
-        new_graph, keep_ids = induced_subgraph(
-            self.remaining_graph, keep_local,
-            chunk_slots=self.divide_chunk, stats=plan.dstats,
-        )
-        state.ext_remaining = state.ext_remaining[keep_local] + ext_delta
-        state.remaining_ids = state.remaining_ids[keep_ids]
-        self.remaining_graph = new_graph
-        self.preprocess_time_s += time.perf_counter() - t0
+        with span("kcore.divide.fold") as sp:
+            newly_mask_local = np.zeros(self.remaining_graph.n_nodes,
+                                        dtype=bool)
+            newly_mask_local[plan.part_local_ids[final_local]] = True
+            keep_local = ~newly_mask_local
+            ext_delta = self._fold_external(
+                self.remaining_graph, keep_local, newly_mask_local,
+                plan.dstats,
+            )
+            new_graph, keep_ids = induced_subgraph(
+                self.remaining_graph, keep_local,
+                chunk_slots=self.divide_chunk, stats=plan.dstats,
+            )
+            state.ext_remaining = state.ext_remaining[keep_local] + ext_delta
+            state.remaining_ids = state.remaining_ids[keep_ids]
+            self.remaining_graph = new_graph
+        self.preprocess_time_s += sp.seconds
         report.divide_transient_bytes = plan.dstats.peak_transient_bytes
 
     def _merge_rest(self, plan: PartPlan, res, density: float,
                     start_sweep: int, annotate=None) -> None:
         state = self.state
-        state.coreness[state.remaining_ids] = res.coreness
-        state.finalized[state.remaining_ids] = True
-        report = self._report_for(
-            plan, res, density, start_sweep, plan.part_g.n_nodes
-        )
-        if annotate is not None:
-            annotate(report)  # wave/slice stamps, before the report is saved
-        self.parts.append(report)
-        state.remaining_ids = np.zeros(0, dtype=np.int64)
-        state.ext_remaining = np.zeros(0, dtype=np.int32)
-        state.complete = True
+        with span("kcore.merge"):
+            state.coreness[state.remaining_ids] = res.coreness
+            state.finalized[state.remaining_ids] = True
+            report = self._report_for(
+                plan, res, density, start_sweep, plan.part_g.n_nodes
+            )
+            if annotate is not None:
+                annotate(report)  # wave/slice stamps, before the save
+            self.parts.append(report)
+            state.remaining_ids = np.zeros(0, dtype=np.int64)
+            state.ext_remaining = np.zeros(0, dtype=np.int32)
+            state.complete = True
         self._checkpoint_boundary(report)
 
     # ---------------- checkpoint stage ---------------- #
@@ -1142,11 +1203,12 @@ class _PartPipeline:
             if report is not None:
                 def on_done(_step, secs, _r=report):
                     _r.save_wall_s = secs
-            blocked = self.state.save(
-                self.checkpoint_dir, manager=self.state_mgr,
-                blocking=not self.overlap, on_done=on_done,
-            )
-            self._purge_sweeps()
+            with span("kcore.checkpoint"):
+                blocked = self.state.save(
+                    self.checkpoint_dir, manager=self.state_mgr,
+                    blocking=not self.overlap, on_done=on_done,
+                )
+                self._purge_sweeps()
             if report is not None:
                 report.save_time_s = blocked
         if self.on_part_done is not None and report is not None:
@@ -1239,10 +1301,12 @@ class _PartPipeline:
                 self.slice_decomposes[s]
                 if self.slice_decomposes is not None else None
             )
-            out = self._conquer(
-                plan, fn=fn, lead=(cursor == lead_cursor), account=False,
-                heartbeat=heartbeat,
-            )
+            with bound(self.recorder), span("kcore.part", **_PART_COUNTS):
+                _count_part(plan)
+                out = self._conquer(
+                    plan, fn=fn, lead=(cursor == lead_cursor), account=False,
+                    heartbeat=heartbeat,
+                )
             # Only slice ``s``'s worker writes index ``s`` — no lock needed.
             self.slice_busy_s[s] += out[0].wall_time_s
             return out
@@ -1339,34 +1403,45 @@ class _PartPipeline:
             self.run_waves()
             return
         state = self.state
-        plan = self._build_plan(state.parts_done)
-        while plan is not None:
-            if plan.is_empty:
-                # No candidates at this threshold: consume the cursor.
-                state.parts_done = plan.cursor + 1
-                self._checkpoint_boundary(None)
-                plan = self._build_plan(plan.cursor + 1)
-                continue
-            self._bucketize(plan)
-            self._submit_prefetch(plan)
-            res, density, start_sweep = self._conquer(plan)
-            if plan.is_rest:
-                self._merge_rest(plan, res, density, start_sweep)
-                plan = None
-                continue
-            report, final_local = self._finalize_threshold(
-                plan, res, density, start_sweep
-            )
-            next_plan = self._shrink(plan, final_local, report)
-            state.parts_done = plan.cursor + 1
-            self._checkpoint_boundary(report)
-            if next_plan is None:
-                next_plan = self._build_plan(plan.cursor + 1)
-            plan = next_plan
+        plan = None  # the next part's plan, when the prefetch built it
+        # A part is left while a threshold is unconsumed or the remaining
+        # graph still holds the rest part.
+        while not state.complete and (
+                state.parts_done < len(self.thresholds)
+                or self.remaining_graph.n_nodes > 0):
+            with span("kcore.part", **_PART_COUNTS):
+                if plan is None:
+                    plan = self._build_plan(state.parts_done)
+                plan = self._run_part(plan)
         if not state.complete:
             # The shrink emptied the graph before the rest part.
             state.complete = True
             self._checkpoint_boundary(None)
+
+    def _run_part(self, plan: PartPlan) -> Optional[PartPlan]:
+        """Bucketize, conquer, merge, fold and checkpoint one part of the
+        sequential loop. Returns the next part's plan when the prefetch
+        built it."""
+        state = self.state
+        _count_part(plan)
+        if plan.is_empty:
+            # No candidates at this threshold: consume the cursor.
+            state.parts_done = plan.cursor + 1
+            self._checkpoint_boundary(None)
+            return None
+        self._bucketize(plan)
+        self._submit_prefetch(plan)
+        res, density, start_sweep = self._conquer(plan)
+        if plan.is_rest:
+            self._merge_rest(plan, res, density, start_sweep)
+            return None
+        report, final_local = self._finalize_threshold(
+            plan, res, density, start_sweep
+        )
+        next_plan = self._shrink(plan, final_local, report)
+        state.parts_done = plan.cursor + 1
+        self._checkpoint_boundary(report)
+        return next_plan
 
     def close(self, suppress_errors: bool = False) -> None:
         """Drain the prefetch worker and both checkpoint managers. Runs on
@@ -1392,6 +1467,7 @@ class _PartPipeline:
                     raise
 
 
+@_recorded
 def dc_kcore(
     g: Graph,
     thresholds: Sequence[int] = (),
@@ -1445,7 +1521,7 @@ def dc_kcore(
     the actual finalized set before being adopted, recomputed synchronously
     on a miss (Exact-Divide always hits by construction). Coreness is
     **byte-identical** with the flag on or off, resume included; only the
-    wall clock and the accelerator-idle fraction change
+    wall clock and the host's share of it outside the conquer change
     (:attr:`DCKCoreReport.idle_fraction`, Fig 16).
 
     ``reorder`` (``"identity"`` / ``"bfs"`` / ``"rcm"``) applies a
@@ -1532,6 +1608,10 @@ def dc_kcore(
     one predecessor, so a corrupted latest step — detected by per-array
     CRC32, quarantined to ``step_*.corrupt`` — resumes from the previous
     retained step instead of restarting the part from scratch).
+
+    Every run records its program spans (:mod:`repro.core.spans`: the job,
+    each part, each divide pass, sweep and merge) on ``report.spans``,
+    summed by :meth:`DCKCoreReport.stage_seconds`.
     """
     slice_decomposes = slice_specs = fold_plan = None
     if part_parallel is not None:

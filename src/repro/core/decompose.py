@@ -76,6 +76,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.hindex import hindex_count, hindex_of_sequence, hindex_sorted
+from repro.core.spans import span
 from repro.graph.structs import BucketedGraph
 from repro.roofline.kcore_model import sweep_cost
 
@@ -495,149 +496,164 @@ def decompose(
     """
     n = bg.n_nodes
     t0 = time.perf_counter()
-    if op == "fused":
-        from repro.kernels.fused import require_fused_platform
+    with span("kcore.conquer.setup"):
+        if op == "fused":
+            from repro.kernels.fused import require_fused_platform
 
-        require_fused_platform()
-    est_dtype = jnp.int32
-    if int16:
-        if op != "fused":
-            raise ValueError("int16=True requires op='fused' (the fused "
-                             "kernel widens in-register; the unfused "
-                             "engines assume int32 state)")
-        max_start = int(
-            (bg.degrees.astype(np.int64) + np.asarray(bg.ext, np.int64))
-            .max(initial=0)
+            require_fused_platform()
+        est_dtype = jnp.int32
+        if int16:
+            if op != "fused":
+                raise ValueError("int16=True requires op='fused' (the fused "
+                                 "kernel widens in-register; the unfused "
+                                 "engines assume int32 state)")
+            max_start = int(
+                (bg.degrees.astype(np.int64) + np.asarray(bg.ext, np.int64))
+                .max(initial=0)
+            )
+            # Overflow guard: estimates start at deg + ext and only decrease,
+            # so int16 is exact iff every start fits. Fall back, never wrap.
+            if max_start < (1 << 15):
+                est_dtype = jnp.int16
+        ext = jnp.asarray(bg.ext, dtype=jnp.int32)
+        ext_pad = jnp.concatenate([ext, jnp.zeros((1,), jnp.int32)])
+        if init_coreness is not None:
+            start = np.asarray(init_coreness)
+            if bg.perm is not None:
+                start = start[bg.perm]  # original-id order -> layout order
+            start = jnp.asarray(start, est_dtype)
+        else:
+            start = (jnp.asarray(bg.degrees, jnp.int32) + ext).astype(est_dtype)
+        c = jnp.concatenate([start, jnp.full((1,), -1, est_dtype)])
+        # Candidate-window bound (exact; see hindex_of_sequence docstring).
+        cand = max(1, hindex_of_sequence(bg.degrees.astype(np.int64) + bg.ext))
+
+        fused_mode = ""
+        groups = None
+        if op == "fused":
+            fused_mode = (
+                "compaction" if len(bg.buckets) >= fused_compaction_min_tiles
+                else "cond"
+            )
+        if fused_mode == "compaction":
+            groups = _FusedGroups(bg)
+            buckets = []
+            tiles_bytes = groups.memory_bytes
+        else:
+            buckets = _device_buckets(bg)
+            tiles_bytes = bg.memory_bytes()
+
+        wire = 2 if est_dtype == jnp.int16 else 4
+        state_bytes = int(c.size * wire + ext_pad.size * 4)
+        peak = tiles_bytes + state_bytes
+
+        n_buckets = len(bg.buckets)
+        bucket_rows = np.array([b.n_rows for b in bg.buckets], dtype=np.int64)
+        bucket_widths = list(bg.widths)
+        adj = bg.bucket_adjacency()
+        active = np.ones(n_buckets, dtype=bool)
+        if seed_nodes is not None:
+            if not frontier:
+                raise ValueError("seed_nodes requires frontier=True (seed "
+                                 "restriction relies on dirty-bit scheduling "
+                                 "to re-activate neighbors)")
+            seeds = np.asarray(seed_nodes)
+            if seeds.dtype == bool:
+                if seeds.shape != (n,):
+                    raise ValueError(f"seed mask shape {seeds.shape} != ({n},)")
+                seeds = np.nonzero(seeds)[0]
+            if bg.inv_perm is not None:
+                # Seeds arrive as original ids; the owner map is in layout
+                # order, and original id o sits at layout row inv_perm[o].
+                seeds = np.asarray(bg.inv_perm)[seeds]
+            owner = bg.node_bucket_map()[:-1][seeds]
+            active = np.zeros(n_buckets, dtype=bool)
+            active[owner[owner >= 0]] = True  # -1: deg-0 rows own no bucket
+        bucket_slots = bucket_rows * np.array(bucket_widths, dtype=np.int64)
+
+        limit = max_iter if max_iter is not None else max(4, n)
+        # Hoisted once: re-uploading the O(n) permutation every sweep would
+        # put an H2D transfer in the hot loop just to build the on_sweep
+        # view.
+        inv_perm_dev = (
+            jnp.asarray(bg.inv_perm)
+            if on_sweep is not None and bg.inv_perm is not None else None
         )
-        # Overflow guard: estimates start at deg + ext and only decrease,
-        # so int16 is exact iff every start fits. Fall back, never wrap.
-        if max_start < (1 << 15):
-            est_dtype = jnp.int16
-    ext = jnp.asarray(bg.ext, dtype=jnp.int32)
-    ext_pad = jnp.concatenate([ext, jnp.zeros((1,), jnp.int32)])
-    if init_coreness is not None:
-        start = np.asarray(init_coreness)
-        if bg.perm is not None:
-            start = start[bg.perm]  # original-id order -> layout order
-        start = jnp.asarray(start, est_dtype)
-    else:
-        start = (jnp.asarray(bg.degrees, jnp.int32) + ext).astype(est_dtype)
-    c = jnp.concatenate([start, jnp.full((1,), -1, est_dtype)])
-    # Candidate-window bound (exact; see hindex_of_sequence docstring).
-    cand = max(1, hindex_of_sequence(bg.degrees.astype(np.int64) + bg.ext))
-
-    fused_mode = ""
-    groups = None
-    if op == "fused":
-        fused_mode = (
-            "compaction" if len(bg.buckets) >= fused_compaction_min_tiles
-            else "cond"
-        )
-    if fused_mode == "compaction":
-        groups = _FusedGroups(bg)
-        buckets = []
-        tiles_bytes = groups.memory_bytes
-    else:
-        buckets = _device_buckets(bg)
-        tiles_bytes = bg.memory_bytes()
-
-    wire = 2 if est_dtype == jnp.int16 else 4
-    state_bytes = int(c.size * wire + ext_pad.size * 4)
-    peak = tiles_bytes + state_bytes
-
-    n_buckets = len(bg.buckets)
-    bucket_rows = np.array([b.n_rows for b in bg.buckets], dtype=np.int64)
-    bucket_widths = list(bg.widths)
-    adj = bg.bucket_adjacency()
-    active = np.ones(n_buckets, dtype=bool)
-    if seed_nodes is not None:
-        if not frontier:
-            raise ValueError("seed_nodes requires frontier=True (seed "
-                             "restriction relies on dirty-bit scheduling "
-                             "to re-activate neighbors)")
-        seeds = np.asarray(seed_nodes)
-        if seeds.dtype == bool:
-            if seeds.shape != (n,):
-                raise ValueError(f"seed mask shape {seeds.shape} != ({n},)")
-            seeds = np.nonzero(seeds)[0]
-        if bg.inv_perm is not None:
-            # Seeds arrive as original ids; the owner map is in layout
-            # order, and original id o sits at layout row inv_perm[o].
-            seeds = np.asarray(bg.inv_perm)[seeds]
-        owner = bg.node_bucket_map()[:-1][seeds]
-        active = np.zeros(n_buckets, dtype=bool)
-        active[owner[owner >= 0]] = True  # -1: deg-0 rows own no bucket
-
-    limit = max_iter if max_iter is not None else max(4, n)
-    # Hoisted once: re-uploading the O(n) permutation every sweep would put
-    # an H2D transfer in the hot loop just to build the on_sweep view.
-    inv_perm_dev = (
-        jnp.asarray(bg.inv_perm)
-        if on_sweep is not None and bg.inv_perm is not None else None
-    )
     comm_per_iter: List[int] = []
     active_rows_per_iter: List[int] = []
-    sweep_bytes_per_iter: List[int] = []
-    sweep_flops_per_iter: List[int] = []
+    active_per_iter: List[np.ndarray] = []
     total = 0
     it = 0
     while it < limit:
-        active_rows_per_iter.append(int(bucket_rows[active].sum()))
-        # Modeled HBM traffic / FLOPs of this sweep's live shape (fig17's
-        # achieved-vs-roofline input; int16 halves the wire terms).
+        active_rows = int(bucket_rows[active].sum())
+        active_rows_per_iter.append(active_rows)
+        active_per_iter.append(active)
+        with span("kcore.sweep", active_tiles=int(active.sum()),
+                  active_rows=active_rows,
+                  swept_slots=int(bucket_slots[active].sum())):
+            if fused_mode == "compaction":
+                c, changed_vec, dirty_next = _compaction_sweep(
+                    groups, c, ext_pad, active, cand,
+                    frozen_reads=not gauss_seidel, track_dirty=frontier,
+                )
+            elif fused_mode == "cond":
+                c, changed_vec, dirty_next = _sweep_fused(
+                    c, ext_pad, buckets, jnp.asarray(active),
+                    cand=cand, frozen_reads=not gauss_seidel,
+                    track_dirty=frontier,
+                )
+            else:
+                c, changed_vec, dirty_next = _sweep(
+                    c, ext_pad, buckets, jnp.asarray(active),
+                    op=op, cand=cand, frozen_reads=not gauss_seidel,
+                    track_dirty=frontier,
+                )
+            with span("kcore.sweep.wait"):
+                changed_vec = np.asarray(changed_vec)
+            changed = int(changed_vec.sum())
+            comm_per_iter.append(changed)
+            total += changed
+            it += 1
+            if on_sweep is not None:
+                # Contract (shared with the distributed engine): int32
+                # values in original-id order. The view stays a lazy device
+                # array — no host sync is forced here — so a hook that
+                # samples every k-th sweep (the sweep-granularity
+                # checkpoints of repro.core.dckcore) pays np.asarray only
+                # when it keeps one.
+                view = c[:-1]
+                if view.dtype != jnp.int32:
+                    view = view.astype(jnp.int32)  # int16: contract is int32
+                if inv_perm_dev is not None:
+                    view = view[inv_perm_dev]  # -> original-id order
+                on_sweep(it, view)
+            if changed == 0:
+                break
+            if frontier:
+                # Next frontier: buckets with a dirty row (a neighbor
+                # changed), intersected with the static bucket-adjacency
+                # certificate — dirty bits refine the bitmap, never widen it.
+                reach = adj[changed_vec > 0].any(axis=0)
+                active = np.asarray(dirty_next) & reach
+    with span("kcore.conquer.readout"):
+        coreness = np.asarray(c[:-1]).astype(np.int32, copy=False)
+        if bg.inv_perm is not None:
+            # layout order -> original-id order
+            coreness = coreness[bg.inv_perm]
+    # Modeled HBM traffic / FLOPs of each sweep's live shape (fig17's
+    # achieved-vs-roofline input; int16 halves the wire terms), priced
+    # once the sweeps are done.
+    sweep_bytes_per_iter: List[int] = []
+    sweep_flops_per_iter: List[int] = []
+    for mask in active_per_iter:
         mb, mf = sweep_cost(
             [(int(bucket_rows[bi]), bucket_widths[bi])
-             for bi in np.nonzero(active)[0]],
+             for bi in np.nonzero(mask)[0]],
             cand, wire_bytes=wire, fused=(op == "fused"),
             track_dirty=frontier,
         )
         sweep_bytes_per_iter.append(mb)
         sweep_flops_per_iter.append(mf)
-        if fused_mode == "compaction":
-            c, changed_vec, dirty_next = _compaction_sweep(
-                groups, c, ext_pad, active, cand,
-                frozen_reads=not gauss_seidel, track_dirty=frontier,
-            )
-        elif fused_mode == "cond":
-            c, changed_vec, dirty_next = _sweep_fused(
-                c, ext_pad, buckets, jnp.asarray(active),
-                cand=cand, frozen_reads=not gauss_seidel,
-                track_dirty=frontier,
-            )
-        else:
-            c, changed_vec, dirty_next = _sweep(
-                c, ext_pad, buckets, jnp.asarray(active),
-                op=op, cand=cand, frozen_reads=not gauss_seidel,
-                track_dirty=frontier,
-            )
-        changed_vec = np.asarray(changed_vec)
-        changed = int(changed_vec.sum())
-        comm_per_iter.append(changed)
-        total += changed
-        it += 1
-        if on_sweep is not None:
-            # Contract (shared with the distributed engine): int32 values
-            # in original-id order. The view stays a lazy device array —
-            # no host sync is forced here — so a hook that samples every
-            # k-th sweep (the sweep-granularity checkpoints of
-            # repro.core.dckcore) pays np.asarray only when it keeps one.
-            view = c[:-1]
-            if view.dtype != jnp.int32:
-                view = view.astype(jnp.int32)  # int16 mode: contract is int32
-            if inv_perm_dev is not None:
-                view = view[inv_perm_dev]  # -> original-id order
-            on_sweep(it, view)
-        if changed == 0:
-            break
-        if frontier:
-            # Next frontier: buckets with a dirty row (a neighbor changed),
-            # intersected with the static bucket-adjacency certificate —
-            # dirty bits refine the bitmap, never widen it.
-            reach = adj[changed_vec > 0].any(axis=0)
-            active = np.asarray(dirty_next) & reach
-    coreness = np.asarray(c[:-1]).astype(np.int32, copy=False)
-    if bg.inv_perm is not None:
-        coreness = coreness[bg.inv_perm]  # layout order -> original-id order
     return DecomposeResult(
         coreness=coreness,
         iterations=it,

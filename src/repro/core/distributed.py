@@ -51,6 +51,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.decompose import DecomposeResult
 from repro.core.hindex import hindex_of_sequence
+from repro.core.spans import span
 from repro.graph.structs import BucketedGraph
 
 
@@ -454,86 +455,96 @@ def decompose_distributed(
     in)."""
     n = bg.n_nodes
     t0 = time.perf_counter()
-    cand = max(1, hindex_of_sequence(bg.degrees.astype(np.int64) + bg.ext))
+    with span("kcore.conquer.setup"):
+        cand = max(1, hindex_of_sequence(bg.degrees.astype(np.int64) + bg.ext))
 
-    mesh = plan.mesh
-    rep_sh = NamedSharding(mesh, P())
-    ext = jnp.asarray(bg.ext, dtype=jnp.int32)
-    ext_pad = jax.device_put(
-        jnp.concatenate([ext, jnp.zeros((1,), jnp.int32)]), rep_sh
-    )
-    if init_coreness is not None:
-        start = np.asarray(init_coreness)
-        if bg.perm is not None:
-            start = start[bg.perm]  # original-id order -> layout order
-        start = jnp.asarray(start, jnp.int32).astype(wire_dtype)
-    else:
-        start = (jnp.asarray(bg.degrees, jnp.int32) + ext).astype(wire_dtype)
-    c = jax.device_put(
-        jnp.concatenate([start, jnp.full((1,), -1, wire_dtype)]),
-        rep_sh,
-    )
-    node_tile = jax.device_put(jnp.asarray(node_tile_map(bg)), rep_sh)
-    buckets = shard_buckets(bg, plan, wire_dtype)
-    sweep = make_sweep_fn(plan, cand, wire_dtype, use_kernel, frontier)(len(buckets))
+        mesh = plan.mesh
+        rep_sh = NamedSharding(mesh, P())
+        ext = jnp.asarray(bg.ext, dtype=jnp.int32)
+        ext_pad = jax.device_put(
+            jnp.concatenate([ext, jnp.zeros((1,), jnp.int32)]), rep_sh
+        )
+        if init_coreness is not None:
+            start = np.asarray(init_coreness)
+            if bg.perm is not None:
+                start = start[bg.perm]  # original-id order -> layout order
+            start = jnp.asarray(start, jnp.int32).astype(wire_dtype)
+        else:
+            start = (jnp.asarray(bg.degrees, jnp.int32) + ext).astype(wire_dtype)
+        c = jax.device_put(
+            jnp.concatenate([start, jnp.full((1,), -1, wire_dtype)]),
+            rep_sh,
+        )
+        node_tile = jax.device_put(jnp.asarray(node_tile_map(bg)), rep_sh)
+        buckets = shard_buckets(bg, plan, wire_dtype)
+        sweep = make_sweep_fn(plan, cand, wire_dtype, use_kernel, frontier)(len(buckets))
 
-    # Peak per-device bytes: sharded tiles + replicated state (coreness,
-    # ext, and the node -> bucket frontier map).
-    ns, ms = plan.n_node_shards, plan.n_slot_shards
-    tile_bytes = sum(int(ids.size * 4 / ns + neigh.size * 4 / (ns * ms)) for ids, neigh in buckets)
-    state_bytes = int(
-        c.size * c.dtype.itemsize
-        + ext_pad.size * 4
-        + node_tile.size * node_tile.dtype.itemsize
-    )
-    peak = tile_bytes + state_bytes
+        # Peak per-device bytes: sharded tiles + replicated state (coreness,
+        # ext, and the node -> bucket frontier map).
+        ns, ms = plan.n_node_shards, plan.n_slot_shards
+        tile_bytes = sum(int(ids.size * 4 / ns + neigh.size * 4 / (ns * ms)) for ids, neigh in buckets)
+        state_bytes = int(
+            c.size * c.dtype.itemsize
+            + ext_pad.size * 4
+            + node_tile.size * node_tile.dtype.itemsize
+        )
+        peak = tile_bytes + state_bytes
 
-    n_buckets = len(bg.buckets)
-    bucket_rows = np.array([b.n_rows for b in bg.buckets], dtype=np.int64)
-    adj = bg.bucket_adjacency()
-    active = np.ones(n_buckets, dtype=bool)
+        n_buckets = len(bg.buckets)
+        bucket_rows = np.array([b.n_rows for b in bg.buckets], dtype=np.int64)
+        adj = bg.bucket_adjacency()
+        active = np.ones(n_buckets, dtype=bool)
+        bucket_slots = bucket_rows * np.array(bg.widths, dtype=np.int64)
 
-    wire_bytes = jnp.dtype(wire_dtype).itemsize
-    limit = max_iter if max_iter is not None else max(4, n)
-    # Hoisted once: no per-sweep H2D upload just to build the hook view.
-    inv_perm_dev = (
-        jnp.asarray(bg.inv_perm)
-        if on_sweep is not None and bg.inv_perm is not None else None
-    )
+        wire_bytes = jnp.dtype(wire_dtype).itemsize
+        limit = max_iter if max_iter is not None else max(4, n)
+        # Hoisted once: no per-sweep H2D upload just to build the hook view.
+        inv_perm_dev = (
+            jnp.asarray(bg.inv_perm)
+            if on_sweep is not None and bg.inv_perm is not None else None
+        )
     comm_per_iter: List[int] = []
     active_rows_per_iter: List[int] = []
     collective_bytes_per_iter: List[int] = []
     total = 0
     it = 0
     while it < limit:
-        active_rows_per_iter.append(int(bucket_rows[active].sum()))
-        collective_bytes_per_iter.append(
-            measured_sweep_bytes(buckets, plan, cand, wire_bytes, active, frontier)
-        )
-        c, changed_vec, dirty_next = sweep(
-            c, ext_pad, jnp.asarray(active), node_tile, buckets
-        )
-        changed_vec = np.asarray(changed_vec)
-        changed = int(changed_vec.sum())
-        comm_per_iter.append(changed)
-        total += changed
-        it += 1
-        if on_sweep is not None:
-            # Lazy int32 view in original-id order (same contract as the
-            # single-device engine): the hook materializes only the
-            # snapshots it keeps.
-            view = c[:-1].astype(jnp.int32)
-            if inv_perm_dev is not None:
-                view = view[inv_perm_dev]
-            on_sweep(it, view)
-        if changed == 0:
-            break
-        if frontier:
-            reach = adj[changed_vec > 0].any(axis=0)
-            active = np.asarray(dirty_next) & reach
-    coreness = np.asarray(c[:-1]).astype(np.int32)
-    if bg.inv_perm is not None:
-        coreness = coreness[bg.inv_perm]  # layout order -> original-id order
+        active_rows = int(bucket_rows[active].sum())
+        active_rows_per_iter.append(active_rows)
+        with span("kcore.sweep", active_tiles=int(active.sum()),
+                  active_rows=active_rows,
+                  swept_slots=int(bucket_slots[active].sum())):
+            collective_bytes_per_iter.append(
+                measured_sweep_bytes(buckets, plan, cand, wire_bytes, active,
+                                     frontier)
+            )
+            c, changed_vec, dirty_next = sweep(
+                c, ext_pad, jnp.asarray(active), node_tile, buckets
+            )
+            with span("kcore.sweep.wait"):
+                changed_vec = np.asarray(changed_vec)
+            changed = int(changed_vec.sum())
+            comm_per_iter.append(changed)
+            total += changed
+            it += 1
+            if on_sweep is not None:
+                # Lazy int32 view in original-id order (same contract as
+                # the single-device engine): the hook materializes only the
+                # snapshots it keeps.
+                view = c[:-1].astype(jnp.int32)
+                if inv_perm_dev is not None:
+                    view = view[inv_perm_dev]
+                on_sweep(it, view)
+            if changed == 0:
+                break
+            if frontier:
+                reach = adj[changed_vec > 0].any(axis=0)
+                active = np.asarray(dirty_next) & reach
+    with span("kcore.conquer.readout"):
+        coreness = np.asarray(c[:-1]).astype(np.int32)
+        if bg.inv_perm is not None:
+            # layout order -> original-id order
+            coreness = coreness[bg.inv_perm]
     return DecomposeResult(
         coreness=coreness,
         iterations=it,

@@ -24,11 +24,11 @@ paper's "limited resources" knob.
 """
 from __future__ import annotations
 
-import time
 from typing import List, Optional, Tuple, Union
 
 import numpy as np
 
+from repro.core.spans import span
 from repro.graph.build import DivideStats, _resolve_chunk_slots, iter_row_ranges
 from repro.graph.structs import Graph
 
@@ -131,17 +131,20 @@ def timed_candidates(
     chunk_slots: Optional[int] = None,
     stats: Optional[DivideStats] = None,
 ) -> Tuple[np.ndarray, float]:
-    """Candidate mask plus extraction wall time (paper Fig 9 measurement)."""
-    t0 = time.perf_counter()
-    if strategy == "rough":
-        mask = rough_candidates(g.degrees, ext, t)
-    elif strategy == "exact":
-        mask = exact_candidates(g, ext, t, chunk_slots=chunk_slots, stats=stats)
-    else:
-        raise ValueError(f"unknown divide strategy: {strategy}")
-    return mask, time.perf_counter() - t0
+    """Candidate mask plus extraction wall time (paper Fig 9 measurement),
+    the seconds of its ``kcore.divide.candidates`` span."""
+    with span("kcore.divide.candidates") as sp:
+        if strategy == "rough":
+            mask = rough_candidates(g.degrees, ext, t)
+        elif strategy == "exact":
+            mask = exact_candidates(g, ext, t, chunk_slots=chunk_slots,
+                                    stats=stats)
+        else:
+            raise ValueError(f"unknown divide strategy: {strategy}")
+    return mask, sp.seconds
 
 
+@span("kcore.plan")
 def plan_thresholds(
     g: Union[Graph, np.ndarray],
     part_budget_bytes: int,

@@ -24,8 +24,9 @@ every K sweeps, so ``--resume`` re-enters a killed run *mid-part* at the
 last completed sweep (falling back to the part boundary when no valid
 snapshot exists). ``--overlap`` turns on the staged pipeline — the next
 part's divide runs on a worker thread and checkpoint saves go async while
-the current part sweeps; coreness is byte-identical either way, and the
-summary reports the accelerator-idle fraction the flag exists to shrink.
+the current part sweeps; coreness is byte-identical either way. The
+summary prints one line of seconds per stage, read from the run's program
+spans (:mod:`repro.core.spans`), and the executables each part built.
 ``--reorder {identity,bfs,rcm}`` applies
 a locality-aware node ordering to each part before tiling
 (``--reorder-sample N`` computes it from an N-slot edge sample);
@@ -63,6 +64,7 @@ import time
 
 from repro.core.dckcore import dc_kcore
 from repro.core.divide import plan_thresholds
+from repro.core.spans import recording, stage_seconds
 from repro.core.partsched import SliceCapacityError
 from repro.graph import barabasi_albert, erdos_renyi, rmat
 from repro.graph.io import (
@@ -157,6 +159,56 @@ def run_with_capacity_replan(
                   f"{shrunk / 2**30:.3f} GB/part -> thresholds {thresholds} "
                   f"(retry {attempt}/{max_replans})")
             dc_kwargs["resume"] = False
+
+
+def stage_line(report, plan_s: float = 0.0) -> str:
+    """One line of host seconds per stage of a run, from its spans: the
+    job, the planner (timed by the caller), the divide passes, the
+    conquer's set-up and read-out, the sweeps with the tiles, rows and
+    padded slots they ran, their wait on the device and their host self
+    time per sweep, the merge and the checkpoint saves."""
+    st = report.stage_seconds()
+
+    def total(name):
+        return st[name].total_s if name in st else 0.0
+
+    sweeps = [r for r in report.spans if r.name == "kcore.sweep"]
+    n = len(sweeps)
+
+    def swept(key):
+        return sum(r.counts.get(key, 0) for r in sweeps)
+
+    wait_ms = 1e3 * total("kcore.sweep.wait") / max(1, n)
+    host_ms = 1e3 * st["kcore.sweep"].self_s / n if n else 0.0
+    return (f"stages (s) of a {total('kcore.job'):.3f}s job: "
+            f"plan {plan_s:.3f}, "
+            f"candidates {total('kcore.divide.candidates'):.3f}, "
+            f"extract {total('kcore.divide.extract'):.3f}, "
+            f"fold {total('kcore.divide.fold'):.3f}, "
+            f"bucketize {total('kcore.divide.bucketize'):.3f}, "
+            f"conquer set-up {total('kcore.conquer.setup'):.3f} "
+            f"read-out {total('kcore.conquer.readout'):.3f}, "
+            f"{n} sweeps {total('kcore.sweep'):.3f} over "
+            f"{swept('active_tiles'):,} tiles, {swept('active_rows'):,} rows, "
+            f"{swept('swept_slots') / 1e6:.1f} Mslots "
+            f"(wait {wait_ms:.2f} ms + host {host_ms:.2f} ms a sweep), "
+            f"merge {total('kcore.merge'):.3f}, "
+            f"checkpoint {total('kcore.checkpoint'):.3f}")
+
+
+def compiles_line(report) -> str:
+    """Executables built (compiled or loaded from the compile cache) while
+    each part ran, in the order the parts started."""
+    parts = []
+    for r in report.spans:
+        if r.name != "kcore.part":
+            continue
+        c = r.counts
+        name = f"core>={c['threshold']}" if "threshold" in c else "rest"
+        parts.append(f"{name} (n={c.get('n_nodes', 0):,} "
+                     f"m={c.get('n_edges', 0):,}) {c.get('compiles', 0)} in "
+                     f"{c.get('compile_ms', 0.0):.0f} ms")
+    return "compiles per part: " + ("; ".join(parts) or "-")
 
 
 def parse_max_bucket_rows(v: str):
@@ -326,8 +378,11 @@ def main():
     budget_bytes = (
         int(args.budget_gb * 2**30) if args.budget_gb is not None else None
     )
+    plan_s = 0.0
     if budget_bytes is not None:
-        thresholds = plan_thresholds(g.degrees, budget_bytes)
+        with recording() as planning:
+            thresholds = plan_thresholds(g.degrees, budget_bytes)
+        plan_s = stage_seconds(planning.records)["kcore.plan"].total_s
         print(f"planned thresholds for {args.budget_gb} GB/part: {thresholds}")
     else:
         thresholds = [int(t) for t in args.thresholds.split(",") if t]
@@ -369,9 +424,8 @@ def main():
           f"(preprocess {report.preprocess_time_s:.2f}s, engine={args.engine}"
           f"{'+int16' if args.int16 else ''}, reorder={args.reorder}, "
           f"overlap={'on' if report.overlap else 'off'})")
-    print(f"accelerator idle fraction: {report.idle_fraction:.3f} "
-          f"(sweeping {report.total_decompose_time_s:.2f}s of "
-          f"{report.total_time_s:.2f}s wall)")
+    print(stage_line(report, plan_s))
+    print(compiles_line(report))
     if report.overlap:
         print(f"prefetch: {report.prefetch_hits} hit(s), "
               f"{report.prefetch_misses} miss(es) recomputed")
