@@ -47,10 +47,11 @@ bucket that is quiescent. Two sound filters compose:
 
   1. the static ``bucket_adj`` bitmap (recorded once at bucketize time):
      a bucket none of whose adjacent buckets changed cannot change;
-  2. per-node dirty bits pushed on device from changed rows of active
-     buckets along their adjacency: a bucket none of whose OWN rows has a
-     changed neighbor cannot change. This is the row-exact refinement that
-     makes skipping effective on power-law graphs, where degree-class
+  2. dirty bits from the rows that changed: each row carries a static
+     tile mask (the buckets its neighbors live in), and the changed rows'
+     masks are OR-reduced on device, so a bucket none of whose OWN rows has
+     a changed neighbor cannot change. This is the row-exact refinement
+     that makes skipping effective on power-law graphs, where degree-class
      adjacency is dense.
 
 A node's estimate is a function of its neighbors' estimates only, so both
@@ -77,7 +78,7 @@ import numpy as np
 
 from repro.core.hindex import hindex_count, hindex_of_sequence, hindex_sorted
 from repro.core.spans import span
-from repro.graph.structs import BucketedGraph
+from repro.graph.structs import BucketedGraph, tile_mask_words
 from repro.roofline.kcore_model import sweep_cost
 
 
@@ -146,8 +147,8 @@ class DecomposeResult:
 
 def _device_buckets(bg: BucketedGraph):
     return [
-        (jnp.asarray(b.node_ids), jnp.asarray(b.neigh), jnp.asarray(b.deg))
-        for b in bg.buckets
+        (jnp.asarray(b.node_ids), jnp.asarray(b.neigh), jnp.asarray(mask))
+        for b, mask in zip(bg.buckets, bg.row_tile_masks())
     ]
 
 
@@ -168,20 +169,24 @@ def _sweep(c, ext_pad, buckets, active, op: str = "sorted", cand: int = 1 << 30,
            frozen_reads: bool = False, track_dirty: bool = True):
     """One sweep over the active buckets.
 
+    ``buckets`` holds one ``(node_ids [rows], neigh [rows, width],
+    mask [rows, W] uint32)`` tuple per bucket, ``mask`` being the rows'
+    tile masks (:meth:`BucketedGraph.row_tile_masks`).
+
     Returns ``(new_c, changed [n_buckets], dirty_next [n_buckets])``:
     ``changed[i]`` counts rows of bucket ``i`` whose estimate changed (the
     paper's communication amount, per bucket); ``dirty_next[j]`` is True iff
-    some row of bucket ``j`` has a neighbor that changed this sweep —
-    changed rows *push* a per-node dirty bit along their adjacency, and each
-    bucket then reads back only its own rows' bits. A node's estimate is a
-    function of its neighbors' estimates, so ``dirty_next`` is exactly the
-    set of buckets that could change next sweep.
+    some row of bucket ``j`` has a neighbor that changed this sweep.
+    Adjacency is symmetric, so that is bit ``j`` of the OR of the changed
+    rows' tile masks: each bucket ORs its changed rows' masks into a ``[W]``
+    word accumulator, ``rows x W`` words and no per-slot work. A node's
+    estimate is a function of its neighbors' estimates, so ``dirty_next``
+    is exactly the set of buckets that could change next sweep.
 
     ``active`` is the [n_buckets] bool frontier mask; inactive buckets skip
     gather + h-index at runtime (``lax.cond``) and report 0 changed rows.
     ``track_dirty=False`` (the always-full-sweep baseline) compiles the
-    dirty-bit push and read-back out entirely and returns an all-False
-    ``dirty_next``.
+    mask OR out entirely and returns an all-False ``dirty_next``.
 
     ``frozen_reads=False`` is Gauss-Seidel: later buckets read estimates
     already updated this sweep (within-sweep propagation, like the paper's
@@ -191,11 +196,12 @@ def _sweep(c, ext_pad, buckets, active, op: str = "sorted", cand: int = 1 << 30,
     sentinel = c.shape[0] - 1
     frozen = c
     new_c = c
-    dirty = jnp.zeros((c.shape[0],), jnp.int8)  # per-node "a neighbor changed"
+    n_words = buckets[0][2].shape[1] if buckets else 1
+    dirty = jnp.zeros((n_words,), jnp.uint32)  # bit j: tile j has a changed neighbor
     changed_parts = []
-    for bi, (node_ids, neigh, _deg) in enumerate(buckets):
+    for bi, (node_ids, neigh, mask) in enumerate(buckets):
 
-        def update(nc, dt, node_ids=node_ids, neigh=neigh):
+        def update(nc, dt, node_ids=node_ids, neigh=neigh, mask=mask):
             src = frozen if frozen_reads else nc
             gathered = src[neigh]  # sentinel slot -> -1
             ext_rows = ext_pad[node_ids]
@@ -206,11 +212,8 @@ def _sweep(c, ext_pad, buckets, active, op: str = "sorted", cand: int = 1 << 30,
             row_changed = (est != cur_rows) & (node_ids != sentinel)
             ch = jnp.sum(row_changed).astype(jnp.int32)
             if track_dirty:
-                # Push dirty bits to every neighbor of a changed row. Work
-                # is proportional to the ACTIVE tile sizes, not the graph.
-                dt = dt.at[neigh].max(
-                    jnp.broadcast_to(row_changed[:, None], neigh.shape).astype(jnp.int8)
-                )
+                hit = jnp.where(row_changed[:, None], mask, jnp.uint32(0))
+                dt = dt | jax.lax.reduce(hit, np.uint32(0), jax.lax.bitwise_or, (0,))
             nc = nc.at[node_ids].set(est)
             nc = nc.at[-1].set(-1)  # re-pin sentinel
             return nc, dt, ch
@@ -223,13 +226,9 @@ def _sweep(c, ext_pad, buckets, active, op: str = "sorted", cand: int = 1 << 30,
         jnp.stack(changed_parts) if changed_parts else jnp.zeros((0,), jnp.int32)
     )
     if track_dirty and buckets:
-        # Each bucket reads back its own rows' dirty bits ([rows] gathers).
-        dirty_next = jnp.stack(
-            [
-                jnp.any((dirty[node_ids] > 0) & (node_ids != sentinel))
-                for node_ids, _neigh, _deg in buckets
-            ]
-        )
+        tile = np.arange(len(buckets))
+        bit = (dirty[tile // 32] >> (tile % 32).astype(np.uint32)) & 1
+        dirty_next = bit.astype(bool)
     else:
         dirty_next = jnp.zeros((len(buckets),), bool)
     return new_c, changed, dirty_next
@@ -252,7 +251,7 @@ def _sweep_fused(c, ext_pad, buckets, active, cand: int = 1 << 30,
     new_c = c
     dirty = jnp.zeros((c.shape[0],), jnp.int8)
     changed_parts = []
-    for bi, (node_ids, neigh, _deg) in enumerate(buckets):
+    for bi, (node_ids, neigh, _mask) in enumerate(buckets):
 
         def update(nc, dt, node_ids=node_ids, neigh=neigh):
             from repro.kernels.fused import fused_sweep_op
@@ -280,7 +279,7 @@ def _sweep_fused(c, ext_pad, buckets, active, cand: int = 1 << 30,
         dirty_next = jnp.stack(
             [
                 jnp.any((dirty[node_ids] > 0) & (node_ids != sentinel))
-                for node_ids, _neigh, _deg in buckets
+                for node_ids, _neigh, _mask in buckets
             ]
         )
     else:
@@ -541,7 +540,8 @@ def decompose(
             tiles_bytes = groups.memory_bytes
         else:
             buckets = _device_buckets(bg)
-            tiles_bytes = bg.memory_bytes()
+            tiles_bytes = (sum(a.nbytes for tile in buckets for a in tile)
+                           + bg.ext.nbytes + bg.degrees.nbytes)
 
         wire = 2 if est_dtype == jnp.int16 else 4
         state_bytes = int(c.size * wire + ext_pad.size * 4)
@@ -590,7 +590,7 @@ def decompose(
         active_per_iter.append(active)
         with span("kcore.sweep", active_tiles=int(active.sum()),
                   active_rows=active_rows,
-                  swept_slots=int(bucket_slots[active].sum())):
+                  swept_slots=int(bucket_slots[active].sum())) as sweep_span:
             if fused_mode == "compaction":
                 c, changed_vec, dirty_next = _compaction_sweep(
                     groups, c, ext_pad, active, cand,
@@ -611,6 +611,7 @@ def decompose(
             with span("kcore.sweep.wait"):
                 changed_vec = np.asarray(changed_vec)
             changed = int(changed_vec.sum())
+            sweep_span.counts["changed_rows"] = changed
             comm_per_iter.append(changed)
             total += changed
             it += 1
@@ -650,7 +651,7 @@ def decompose(
             [(int(bucket_rows[bi]), bucket_widths[bi])
              for bi in np.nonzero(mask)[0]],
             cand, wire_bytes=wire, fused=(op == "fused"),
-            track_dirty=frontier,
+            track_dirty=frontier, mask_words=tile_mask_words(n_buckets),
         )
         sweep_bytes_per_iter.append(mb)
         sweep_flops_per_iter.append(mf)

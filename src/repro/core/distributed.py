@@ -513,7 +513,7 @@ def decompose_distributed(
         active_rows_per_iter.append(active_rows)
         with span("kcore.sweep", active_tiles=int(active.sum()),
                   active_rows=active_rows,
-                  swept_slots=int(bucket_slots[active].sum())):
+                  swept_slots=int(bucket_slots[active].sum())) as sweep_span:
             collective_bytes_per_iter.append(
                 measured_sweep_bytes(buckets, plan, cand, wire_bytes, active,
                                      frontier)
@@ -524,6 +524,7 @@ def decompose_distributed(
             with span("kcore.sweep.wait"):
                 changed_vec = np.asarray(changed_vec)
             changed = int(changed_vec.sum())
+            sweep_span.counts["changed_rows"] = changed
             comm_per_iter.append(changed)
             total += changed
             it += 1

@@ -78,6 +78,7 @@ from repro.core.distributed import (
     planned_live_sets,
 )
 from repro.core.hindex import hindex_of_sequence
+from repro.graph.structs import tile_mask_words
 from repro.roofline.kcore_model import sweep_cost
 
 # Wave-conquer worker threads carry this name prefix; the test suite
@@ -230,6 +231,7 @@ def part_cost(
         b, _f = sweep_cost(
             [(padded[bi], bucket_shapes[bi][1]) for bi in live],
             cand, wire_bytes=wire_bytes, fused=False, track_dirty=frontier,
+            mask_words=tile_mask_words(len(bucket_shapes)),
         )
         hbm += b // spec.n_devices
     # Per-device resident footprint: sharded tiles + replicated state

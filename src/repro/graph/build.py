@@ -32,7 +32,13 @@ from typing import Dict, Iterator, Optional, Sequence, Tuple
 
 import numpy as np
 
-from repro.graph.structs import Bucket, BucketedGraph, Graph
+from repro.graph.structs import (
+    Bucket,
+    BucketedGraph,
+    Graph,
+    build_tile_masks,
+    tile_mask_bits,
+)
 
 # Bucket pad widths: powers of two. Smallest kept modest so tiny-degree nodes
 # don't blow up the padded footprint; largest grows to cover any max degree.
@@ -447,7 +453,8 @@ def bucketize(
     * ``None`` — no splitting: exactly one tile per degree class (coarsest
       frontier, smallest trace; the pre-frontier layout).
 
-    The ``bucket_adj`` bitmap over tiles is recorded for the engines.
+    The per-row tile masks and the ``bucket_adj`` bitmap over tiles they
+    OR to are recorded for the engines.
 
     If ``g`` is reordered (``g.perm`` set), ``ext`` must be given in
     **original**-id order — it is permuted into the layout order here, and
@@ -498,22 +505,24 @@ def bucketize(
                 Bucket(node_ids=node_ids, neigh=neigh, deg=row_deg, width=width)
             )
 
-    # Bucket-adjacency bitmap for frontier scheduling. An endpoint of any
-    # edge has degree >= 1, so every real neighbor id maps to a bucket;
-    # sentinel-padded slots map to -1 and are dropped. Diagonal is kept set
-    # (conservative: a bucket that changed rescans itself next sweep) and the
-    # matrix is symmetrized — CSR symmetry makes it symmetric already, but
-    # padding asymmetries must never weaken the soundness argument.
+    # Row tile masks (the sweep's dirty bits) and, from their OR over each
+    # bucket's rows, the bucket-adjacency bitmap for frontier scheduling.
+    # An endpoint of any edge has degree >= 1, so every real neighbor id
+    # maps to a bucket; sentinel-padded slots map to -1 and set no bit.
+    # Diagonal is kept set (conservative: a bucket that changed rescans
+    # itself next sweep) and the matrix is symmetrized — CSR symmetry makes
+    # it symmetric already, but padding asymmetries must never weaken the
+    # soundness argument.
     nb = len(buckets)
+    masks = build_tile_masks(buckets, node_bucket)
     adj = np.zeros((nb, nb), dtype=bool)
+    for bi, mask in enumerate(masks):
+        adj[bi] = tile_mask_bits(np.bitwise_or.reduce(mask, axis=0), nb)
     np.fill_diagonal(adj, True)
-    for bi, b in enumerate(buckets):
-        touched = np.unique(node_bucket[b.neigh.ravel()])
-        adj[bi, touched[touched >= 0]] = True
     adj |= adj.T
 
     return BucketedGraph(
         n_nodes=n, buckets=buckets, ext=ext, degrees=deg.astype(np.int32),
         bucket_adj=adj, node_bucket=node_bucket,
-        perm=g.perm, inv_perm=g.inv_perm,
+        perm=g.perm, inv_perm=g.inv_perm, tile_masks=masks,
     )

@@ -165,8 +165,9 @@ def stage_line(report, plan_s: float = 0.0) -> str:
     """One line of host seconds per stage of a run, from its spans: the
     job, the planner (timed by the caller), the divide passes, the
     conquer's set-up and read-out, the sweeps with the tiles, rows and
-    padded slots they ran, their wait on the device and their host self
-    time per sweep, the merge and the checkpoint saves."""
+    padded slots they ran and the rows that changed, their wait on the
+    device and their host self time per sweep, the merge and the checkpoint
+    saves."""
     st = report.stage_seconds()
 
     def total(name):
@@ -190,7 +191,8 @@ def stage_line(report, plan_s: float = 0.0) -> str:
             f"read-out {total('kcore.conquer.readout'):.3f}, "
             f"{n} sweeps {total('kcore.sweep'):.3f} over "
             f"{swept('active_tiles'):,} tiles, {swept('active_rows'):,} rows, "
-            f"{swept('swept_slots') / 1e6:.1f} Mslots "
+            f"{swept('swept_slots') / 1e6:.1f} Mslots, "
+            f"{swept('changed_rows'):,} changed rows "
             f"(wait {wait_ms:.2f} ms + host {host_ms:.2f} ms a sweep), "
             f"merge {total('kcore.merge'):.3f}, "
             f"checkpoint {total('kcore.checkpoint'):.3f}")

@@ -189,7 +189,9 @@ def test_swept_slots_are_rows_times_width_of_the_tiles_run(
     slots = np.array([b.neigh.shape[0] * b.neigh.shape[1]
                       for b in bg.buckets])
     rows = np.array([b.neigh.shape[0] for b in bg.buckets])
-    for s, mask, active_rows in zip(sweeps, masks, res.active_rows_per_iter):
+    for s, mask, active_rows, changed in zip(
+            sweeps, masks, res.active_rows_per_iter, res.comm_per_iter):
+        assert s.counts["changed_rows"] == changed
         assert s.counts["swept_slots"] == int(slots[mask].sum())
         assert s.counts["active_rows"] == int(rows[mask].sum()) == active_rows
         assert s.counts["active_tiles"] == int(mask.sum())
@@ -363,7 +365,8 @@ def test_cli_prints_stage_seconds_and_compiles(monkeypatch, capsys):
     (line,) = [ln for ln in out.splitlines() if ln.startswith("stages (s)")]
     for word in ("job", "plan", "candidates", "extract", "fold", "bucketize",
                  "conquer set-up", "read-out", "sweeps", "tiles", "rows",
-                 "Mslots", "wait", "host", "merge", "checkpoint"):
+                 "Mslots", "changed rows", "wait", "host", "merge",
+                 "checkpoint"):
         assert word in line
     (comp,) = [ln for ln in out.splitlines()
                if ln.startswith("compiles per part:")]

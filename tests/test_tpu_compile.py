@@ -126,19 +126,43 @@ def _buckets_and_cand(scale):
     return g, bg, cand
 
 
+def _sweep_specs(bg, one_chip):
+    return [(_spec(b.node_ids.shape, one_chip),
+             _spec(b.neigh.shape, one_chip),
+             _spec(mask.shape, one_chip, jnp.uint32))
+            for b, mask in zip(bg.buckets, bg.row_tile_masks())]
+
+
 def test_kernel_engine_sweep_compiles_for_v5e(one_chip, on_tpu):
     """The ``engine="kernel"`` sweep over one real tile set: every bucket's
     h-index is a Mosaic kernel, none is interpreted."""
     g, bg, cand = _buckets_and_cand(9)
     n = g.n_nodes
-    buckets = [(_spec(b.node_ids.shape, one_chip),
-                _spec(b.neigh.shape, one_chip),
-                _spec(b.deg.shape, one_chip)) for b in bg.buckets]
+    buckets = _sweep_specs(bg, one_chip)
     compiled = _sweep.lower(
         _spec((n + 1,), one_chip), _spec((n + 1,), one_chip), buckets,
         _spec((len(buckets),), one_chip, jnp.bool_), op="kernel", cand=cand,
     ).compile()
     assert compiled.as_text().count("tpu_custom_call") >= len(bg.buckets)
+
+
+def test_sorted_sweep_scatters_no_slots_for_v5e(one_chip):
+    """The sorted engine's sweep with the frontier on, as the chip's
+    compiler emits it: every scatter writes the int32 estimate vector (one
+    row write per tile); the dirty bits take no scatter over slots."""
+    import re
+
+    g, bg, cand = _buckets_and_cand(9)
+    n = g.n_nodes
+    text = _sweep.lower(
+        _spec((n + 1,), one_chip), _spec((n + 1,), one_chip),
+        _sweep_specs(bg, one_chip),
+        _spec((len(bg.buckets),), one_chip, jnp.bool_), op="sorted",
+        cand=cand,
+    ).compile().as_text()
+    scatters = re.findall(r"= (\S+) scatter\(", text)
+    assert scatters
+    assert all(s.startswith(f"s32[{n + 1}]") for s in scatters), scatters
 
 
 def test_sharded_kernel_sweep_compiles_for_2x2(topo, on_tpu):
