@@ -13,9 +13,11 @@ one). The benchmark's own runs never run the control.
 
 The control breaks the configuration's guarantee, exact coreness, in the
 way that would tempt a faster program: it stops each part's h-index fixed
-point early. It is the program's own ``decompose`` with its ``max_iter``
-path switched on, capped two sweeps short of the count the part needs, so
-only the last sweep that still changes an estimate is skipped.
+point early. It is the program's own engine with its ``max_iter`` path
+switched on, capped two sweeps short of the count the part needs, so only
+the last sweep that still changes an estimate is skipped. On a mix with a
+mesh plan the engine is each slice's ``decompose_distributed``, which
+``dc_kcore`` builds itself; on any other mix it is ``decompose``.
 """
 from __future__ import annotations
 
@@ -32,19 +34,39 @@ if ROOT not in sys.path:
 from bench import graphs, job as jobs_mod, reference, run as bench_run  # noqa: E402
 
 
+def two_short(fn):
+    """The engine ``fn`` capped two sweeps short of its fixed point."""
+
+    def early(bg, **kw):
+        full = fn(bg, **kw)
+        return fn(bg, max_iter=max(1, full.iterations - 2), **kw)
+
+    return early
+
+
 def control_dc(g, thresholds=(), **kw):
     """``dc_kcore`` whose engine stops two sweeps short of the fixed point."""
+    from repro.core import partsched
     from repro.core.dckcore import dc_kcore
     from repro.core.decompose import decompose
 
-    engine, int16 = kw.pop("engine"), kw.pop("int16")
+    if kw.get("part_parallel_plan") is None:
+        engine, int16 = kw.pop("engine"), kw.pop("int16")
+        return dc_kcore(g, thresholds, **kw, decompose_fn=two_short(
+            lambda bg, **dkw: decompose(bg, op=engine, int16=int16, **dkw)))
+    # dc_kcore builds one distributed engine per mesh slice and refuses a
+    # decompose_fn beside a plan, so the cap goes on the engines it builds.
+    real = partsched.make_slice_decomposes
 
-    def early(bg, **dkw):
-        full = decompose(bg, op=engine, int16=int16, **dkw)
-        return decompose(bg, op=engine, int16=int16,
-                         max_iter=max(1, full.iterations - 2), **dkw)
+    def slice_decomposes(plan, n_slices, **ekw):
+        plans, fns = real(plan, n_slices, **ekw)
+        return plans, [two_short(f) for f in fns]
 
-    return dc_kcore(g, thresholds, decompose_fn=early, **kw)
+    partsched.make_slice_decomposes = slice_decomposes
+    try:
+        return dc_kcore(g, thresholds, **kw)
+    finally:
+        partsched.make_slice_decomposes = real
 
 
 def parse_seeds(text: str):
@@ -66,16 +88,17 @@ def main(argv=None, *, require_accelerator: bool = True,
 
     from repro.graph.structs import Graph
 
+    if bench_run.accelerator(jax, int(cell["chips"]), require_accelerator,
+                             jobs_mod.devices_of(traffic)) is None:
+        return 2
     if require_accelerator:
-        if bench_run.accelerator(jax, int(cell["chips"])) is None:
-            return 2
         bench_run.use_compile_cache(jax)
-    kwargs = jobs_mod.dc_kwargs(traffic)
     sound, control = [], []
     for seed in sorted(set(args.seeds) | set(args.control_seeds)):
         csr = graphs.make_graph(config, seed)
         g = Graph(indptr=csr.indptr, indices=csr.indices, n_nodes=csr.n)
         budget = jobs_mod.budget_bytes(traffic, csr.degrees)
+        kwargs = jobs_mod.dc_kwargs(traffic, budget)
         ref = reference.coreness(csr.indptr, csr.indices)
         line = {"seed": seed, "n": csr.n, "m": csr.m}
         if seed in args.seeds:

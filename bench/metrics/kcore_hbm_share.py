@@ -1,5 +1,6 @@
-"""Percent of the chip's HBM bandwidth that one job's least traffic would
-take over the device-busy seconds of one job.
+"""Percent of the cell's HBM bandwidth that one job's least traffic would
+take over the device-busy seconds of one job: busy seconds per chip over
+all the cell's chips, against their summed bandwidth.
 
 The least traffic is what any exact k-core decomposition must move, priced
 as one sweep of ``sweep_tile_cost`` (``roofline/kcore_model.py``) at its
@@ -22,4 +23,4 @@ def read(run):
     if busy_per_job <= 0:
         return None
     return 100.0 * least_bytes(run.n, run.m) / busy_per_job \
-        / run.peaks["hbm_bytes_per_s"]
+        / (run.chips * run.peaks["hbm_bytes_per_s"])

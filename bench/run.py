@@ -18,11 +18,16 @@ cell's per-layer metrics instead of its end-to-end ones. After the window
 every job's coreness, the warm-up's included, is compared vertex by vertex
 with the plain reference (``bench/reference.py``) on the same graph.
 
+A mix that names ``devices`` runs its jobs over the first ``devices`` of
+the cell's chips (``bench/job.py``); device busy time and the shares priced
+from it are taken over all the cell's chips, an idle one included.
+
 The last line of stdout is one JSON object: ``correct``, ``attempted``,
 ``failed``, ``metrics``, ``device`` (and ``breakdown`` with a trace), and
 last ``checks``, each compared number with its limit. The run exits non-zero
 without that line when JAX finds no TPU or fewer chips than the cell asks
-for. JAX's compile cache is kept in ``.jax_cache`` at the checkout's root.
+for, or when the mix names more devices than the cell has chips. JAX's
+compile cache is kept in ``.jax_cache`` at the checkout's root.
 """
 from __future__ import annotations
 
@@ -64,7 +69,8 @@ class Run:
     jobs: list                      # bench.job.Job, the window's
     n: int
     m: int
-    peak_bytes: Optional[int]
+    chips: int                      # the cell's chips
+    peak_bytes: Optional[int]       # on the fullest chip
     peaks: Optional[dict]           # bench/peaks.json row; None off the chip
     trace: Optional[xplane.Summary]
     window_built: int               # compiles + cache loads in the window
@@ -116,10 +122,16 @@ def log(msg: str) -> None:
     print(msg, file=sys.stderr, flush=True)
 
 
-def accelerator(jax, chips: int):
-    """The cell's devices, or None (with the reason on stderr)."""
+def accelerator(jax, chips: int, require_tpu: bool = True,
+                mix_devices: int = 1):
+    """The cell's devices, or None (with the reason on stderr). A mix that
+    runs on more devices than the cell has chips is refused too."""
+    if mix_devices > chips:
+        log(f"bench: the mix runs on {mix_devices} devices, the cell has "
+            f"{chips} chips")
+        return None
     devices = jax.devices()
-    if devices[0].platform != "tpu":
+    if require_tpu and devices[0].platform != "tpu":
         log(f"bench: JAX finds no TPU (platform {devices[0].platform!r})")
         return None
     if len(devices) < chips:
@@ -164,28 +176,28 @@ def main(argv=None, *, require_accelerator: bool = True,
     args = parse_args(sys.argv[1:] if argv is None else argv)
     spec, cell, config, traffic = load_cell(args.workload)
     config = {**config, **(overrides or {})}
+    chips = int(cell["chips"])
     if os.path.join(ROOT, "src") not in sys.path:
         sys.path.insert(0, os.path.join(ROOT, "src"))
     import jax
 
     from repro.graph.structs import Graph
 
+    devices = accelerator(jax, chips, require_accelerator,
+                          jobs_mod.devices_of(traffic))
+    if devices is None:
+        return 2
     peaks = None
     if require_accelerator:
-        devices = accelerator(jax, int(cell["chips"]))
-        if devices is None:
-            return 2
         peaks = peaks_mod.lookup(devices[0].device_kind)
         use_compile_cache(jax)
-    else:
-        devices = jax.devices()[:1]
     clock = CompileClock(jax)
 
     t = time.perf_counter()
     csr = graphs.make_graph(config, args.seed)
     g = Graph(indptr=csr.indptr, indices=csr.indices, n_nodes=csr.n)
     budget = jobs_mod.budget_bytes(traffic, csr.degrees)
-    kwargs = jobs_mod.dc_kwargs(traffic)
+    kwargs = jobs_mod.dc_kwargs(traffic, budget)
     log(f"graph {config['name']} seed={args.seed}: n={csr.n} m={csr.m} "
         f"max_deg={int(csr.degrees.max())} budget_bytes={budget} "
         f"({time.perf_counter() - t:.3f}s)")
@@ -194,6 +206,12 @@ def main(argv=None, *, require_accelerator: bool = True,
         f"parts={len(warm.report.parts)} built={clock.built} "
         f"({clock.build_s:.3f}s) compiled={clock.compiled} "
         f"cache_loads={clock.cache_loads}")
+    if warm.report.part_parallel:
+        log(f"part-parallel: {warm.report.part_parallel} slices, part "
+            f"slices {[p.slice_index for p in warm.report.parts]}, waves "
+            f"{[p.wave for p in warm.report.parts]}, re-divides "
+            f"{warm.replans}, boundary-exchange bytes "
+            f"{warm.report.boundary_exchange_bytes}")
 
     if args.trace:
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
@@ -215,7 +233,8 @@ def main(argv=None, *, require_accelerator: bool = True,
     if args.trace:
         jax.profiler.stop_trace()
         t = time.perf_counter()
-        summary = xplane.summarize(TRACE_DIR, devices[0].platform, WINDOW_SPAN)
+        summary = xplane.summarize(TRACE_DIR, devices[0].platform,
+                                   WINDOW_SPAN, len(devices))
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
         log(f"trace: {summary.n_ops} device ops, busy {summary.busy_s:.3f}s "
             f"of {summary.window_s:.3f}s, read in "
@@ -228,8 +247,8 @@ def main(argv=None, *, require_accelerator: bool = True,
                    f" {j.report.total_iterations}]" for j in window))
 
     run = Run(setup_s=setup_s, window_s=window_s, jobs=window, n=csr.n,
-              m=csr.m, peak_bytes=peak, peaks=peaks, trace=summary,
-              window_built=window_built)
+              m=csr.m, chips=len(devices), peak_bytes=peak, peaks=peaks,
+              trace=summary, window_built=window_built)
     metrics = {}
     for m in metrics_of(spec, cell["name"], bool(args.trace)):
         value = reader(m["name"])(run)

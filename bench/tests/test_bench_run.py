@@ -73,7 +73,7 @@ def test_reports_of_a_tiny_job_feed_every_per_layer_reader():
     trace = Summary(busy_s=0.5, window_s=2.0, n_devices=1, n_ops=10,
                     device_ops=[], idle_gaps=[])
     r = run.Run(setup_s=3.0, window_s=2.0, jobs=[job, job], n=csr.n, m=csr.m,
-                peak_bytes=2**20, peaks=peaks.lookup("TPU v5 lite"),
+                chips=1, peak_bytes=2**20, peaks=peaks.lookup("TPU v5 lite"),
                 trace=trace, window_built=0)
     for m in SPEC["per_layer"] + SPEC["end_to_end"]:
         value = run.reader(m["name"])(r)

@@ -3,6 +3,7 @@ the test records on the CPU backend."""
 import os
 import sys
 import time
+import types
 
 import pytest
 
@@ -10,7 +11,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from bench import xplane  # noqa: E402
+from bench import run, xplane  # noqa: E402
 from bench.xplane import Op, Span  # noqa: E402
 
 
@@ -42,11 +43,44 @@ def test_reduce_busy_gaps_and_names():
 def test_reduce_averages_over_devices_and_caps_lists():
     ops = {f"/device:TPU:{d}": [Op(f"op{i}", 10 * i, 10 * i + 5, "m")
                                 for i in range(20)] for d in range(2)}
-    s = xplane.reduce(ops, [], (0, 200))
+    s = xplane.reduce(ops, [], (0, 200), chips=2)
     assert s.n_devices == 2
     assert s.busy_s == pytest.approx(100 / 1e9)
     assert len(s.device_ops) == xplane.TOP
     assert len(s.idle_gaps) <= xplane.TOP
+
+
+def test_a_chip_idle_all_window_counts_in_the_average():
+    # Four chips; the fourth runs nothing, so the trace has no ops for it.
+    ops = {f"/device:TPU:{d}": [Op("sweep", 0, 60, "jit__sweep")]
+           for d in range(3)}
+    s = xplane.reduce(ops, [Span("bench.job", 0, 100)], (0, 100), chips=4)
+    assert s.n_devices == 4
+    assert s.busy_s == pytest.approx(3 * 60 / 4 / 1e9)
+    assert s.idle_share == pytest.approx(1 - 180 / 400)
+    assert dict(s.device_ops)["jit__sweep/sweep"] == pytest.approx(45 / 1e9)
+    gaps = dict(s.idle_gaps)
+    assert gaps["bench.job before window end"] == pytest.approx(3 * 40 / 4 / 1e9)
+    assert gaps[xplane.IDLE_CHIP] == pytest.approx(100 / 4 / 1e9)
+    assert sum(gaps.values()) == pytest.approx(s.window_s - s.busy_s)
+
+
+def test_reduce_refuses_more_devices_than_chips():
+    ops = {f"/device:TPU:{d}": [Op("op", 0, 5, "m")] for d in range(2)}
+    with pytest.raises(ValueError, match="2 devices"):
+        xplane.reduce(ops, [], (0, 10), chips=1)
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_hbm_share_prices_every_chip(chips):
+    read = run.reader("kcore_hbm_share")
+    trace = xplane.Summary(busy_s=2.0, window_s=4.0, n_devices=chips,
+                           n_ops=1, device_ops=[], idle_gaps=[])
+    r = types.SimpleNamespace(trace=trace, jobs=[None, None], n=1000,
+                              m=8000, chips=chips,
+                              peaks={"hbm_bytes_per_s": 819e9})
+    least = 2 * 8000 * 8 + 1000 * 20
+    assert read(r) == pytest.approx(100 * least / 1.0 / (chips * 819e9))
 
 
 def test_reduce_refuses_a_trace_without_device_ops():

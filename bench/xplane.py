@@ -10,6 +10,10 @@ window with no operation. Each gap is named after the innermost benchmark
 span (``bench.*``, written by ``jax.profiler.TraceAnnotation``) that holds
 its midpoint, and after the device operation that ends it, so that a gap
 before a sweep reads differently from one before a divide pass.
+
+Busy time, each operation's time and each gap are averaged over the chips
+the run used, whether or not a chip ran anything in the window: a chip
+that idles throughout counts as idle, not as absent.
 """
 from __future__ import annotations
 
@@ -23,6 +27,7 @@ from typing import Dict, List, Optional, Tuple
 
 SPAN_PREFIX = "bench."
 TOP = 10
+IDLE_CHIP = "chip idle all window"
 
 
 @dataclasses.dataclass
@@ -44,7 +49,7 @@ class Span:
 class Summary:
     busy_s: float                       # union of op intervals, per chip
     window_s: float
-    n_devices: int
+    n_devices: int                      # chips averaged over
     n_ops: int
     device_ops: List[Tuple[str, float]]  # top ops by summed device seconds
     idle_gaps: List[Tuple[str, float]]   # longest gaps, named by host span
@@ -145,11 +150,15 @@ def _innermost(spans: List[Span], t: float) -> str:
 
 
 def reduce(ops: Dict[str, List[Op]], spans: List[Span],
-           window: Tuple[float, float]) -> Summary:
-    """Busy time and breakdown of the window ``(start_ns, end_ns)``."""
+           window: Tuple[float, float], chips: int = 1) -> Summary:
+    """Busy time and breakdown of the window ``(start_ns, end_ns)``, per
+    chip of the ``chips`` the run used."""
     w0, w1 = window
     if not ops:
         raise ValueError("the trace holds no device operation")
+    if len(ops) > chips:
+        raise ValueError(f"the trace has operations on {len(ops)} devices, "
+                         f"the run used {chips}")
     busy_total = 0.0
     per_op: Dict[str, float] = defaultdict(float)
     gaps: Dict[str, float] = defaultdict(float)
@@ -177,7 +186,10 @@ def reduce(ops: Dict[str, List[Op]], spans: List[Span],
                 else "window end"
             gaps[f"{_innermost(spans, (g0 + g1) / 2)} before {then}"] += \
                 g1 - g0
-    n_dev = len(ops)
+    # A chip with no operation in the trace idled through the window.
+    if chips > len(ops):
+        gaps[IDLE_CHIP] += (chips - len(ops)) * (w1 - w0)
+    n_dev = chips
     top_ops = sorted(per_op.items(), key=lambda kv: -kv[1])[:TOP]
     top_gaps = sorted(gaps.items(), key=lambda kv: -kv[1])[:TOP]
     return Summary(
@@ -198,10 +210,14 @@ def window_of(spans: List[Span], name: str) -> Tuple[float, float]:
     return hits[0].start_ns, hits[0].end_ns
 
 
-def summarize(log_dir: str, platform: str, window_span: str) -> Summary:
-    """Read the trace under ``log_dir`` and reduce the span ``window_span``."""
+def summarize(log_dir: str, platform: str, window_span: str,
+              chips: int = 1) -> Summary:
+    """Read the trace under ``log_dir`` and reduce the span ``window_span``
+    over the run's ``chips``. The CPU backend's devices all run on the
+    host's threads, which ``collect`` reads as one device."""
     from jax.profiler import ProfileData
 
     profile = ProfileData.from_file(find_xplane(log_dir))
     ops, spans = collect(profile, platform)
-    return reduce(ops, spans, window_of(spans, window_span))
+    return reduce(ops, spans, window_of(spans, window_span),
+                  1 if platform == "cpu" else chips)
